@@ -784,13 +784,11 @@ def bench_serving_prefill_heavy(quick: bool):
     prefill lowering (ROADMAP: the last non-Pallas hot path until this PR).
 
     Two engines differ ONLY in ``attn_impl``: "xla_chunked" pins the
-    reference lowering, "pallas" dispatches the Pallas chunk-prefill (and
-    decode) kernels on TPU and falls back to the identical reference path
-    on CPU with a one-time warning — so on this container the two rows
-    must be statistically equal (the acceptance bound: kernel-path TTFT no
-    worse than ref), while on a TPU host the same bench measures the fused
-    kernel. Best-of-3 with the engines alternated, like the shared-prefix
-    bench."""
+    reference lowering, "auto" dispatches the Pallas chunk-prefill (and
+    decode) kernels on TPU and resolves to the same reference path off-TPU
+    — so on a CPU host the two rows are one lowering timed twice, while on
+    a TPU host the same bench measures the kernels. Best-of-3 with the
+    engines alternated, like the shared-prefix bench."""
     import jax
 
     from repro.configs import ARCHS, reduced
@@ -822,7 +820,7 @@ def bench_serving_prefill_heavy(quick: bool):
             prefill_chunk=chunk, attn_impl=attn_impl,
         )
 
-    ref_eng, kern_eng = make("xla_chunked"), make("pallas")
+    ref_eng, kern_eng = make("xla_chunked"), make("auto")
 
     def one_run(engine):
         t0 = time.perf_counter()
@@ -853,9 +851,9 @@ def bench_serving_prefill_heavy(quick: bool):
         "max_new": [4, 8], "slots": slots, "prefill_chunk": chunk,
         "max_len": max_len, "best_of": 3,
         "kernel_backend": jax.default_backend(),
-        # off-TPU the "pallas" engine serves through the ref fallback, so
-        # equal rows mean "fallback costs nothing", not "kernel measured"
-        "kernel_fallback_to_ref": jax.default_backend() != "tpu",
+        # off-TPU "auto" resolves to the ref lowering: equal rows then
+        # mean "same lowering", not "kernel measured"
+        "kernel_resolves_to_ref": jax.default_backend() != "tpu",
     }}
     serving_entry("bench_serving_prefill_heavy", "ref_prefill",
                   tok_per_s=useful / ref_s, results=ref_res)
@@ -1159,6 +1157,9 @@ def main() -> None:
                          "(e.g. --only serving regenerates the serving "
                          "sections of BENCH_serving.json)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     t0 = time.time()
     benches = (bench_split, bench_bus, bench_storage, bench_ckpt,

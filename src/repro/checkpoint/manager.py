@@ -31,11 +31,9 @@ from typing import Any
 import jax
 import numpy as np
 
-from repro.compat import tree_leaves_with_path
-
 
 def _tree_paths(tree: Any) -> list[tuple[str, Any]]:
-    leaves = tree_leaves_with_path(tree)
+    leaves = jax.tree.leaves_with_path(tree)
     return [(jax.tree_util.keystr(p), v) for p, v in leaves]
 
 

@@ -1,4 +1,4 @@
-"""jit'd public wrappers around the Pallas kernels with XLA fallbacks.
+"""jit'd public wrappers around the Pallas kernels, with XLA references.
 
 Ops: ``flash_attention`` (train/prefill), ``paged_attention`` (single-token
 decode over the serving page pool), ``paged_prefill_attention`` (chunked
@@ -7,30 +7,27 @@ one prefill chunk, one dispatch per engine step), ``ssd_scan`` /
 ``ssd_decode_step`` (Mamba2).
 
 ``impl`` selection:
-  * "pallas"      — the Pallas TPU kernel. On a non-TPU backend every op
-                    falls back to the ``ref.py`` path with a one-time
-                    warning (a compiled Pallas lowering needs TPU
-                    hardware), so a TPU-tuned launch config still serves
-                    correctly on CPU hosts.
+  * "pallas"      — the compiled Pallas TPU kernel. It needs a TPU backend:
+                    on any other backend every op raises
+                    :class:`PallasUnavailableError` — there is no silent
+                    fallback that would hide which device served.
   * "pallas_interpret" — the Pallas kernel in interpret mode on any backend
                     (tests, the differential kernel-fuzz harness, and the
                     kernel-path engine parity suite use this on CPU).
   * "xla_chunked" — pure-jnp chunked implementations from ``ref.py``
-                    (bounded memory; the default lowering path everywhere in
-                    this repo since the container has no TPU).
+                    (bounded memory; the reference lowering the chip smoke
+                    compares the kernels against).
   * "naive"       — full-matrix references (tests/small inputs only).
   * "auto"        — "pallas" on TPU backends, else "xla_chunked".
 
 Contract: for every op the ``ref.py`` implementation is the ground truth;
 kernels must match it within the tolerance asserted in ``tests/``
 (``tests/test_kernel_fuzz.py`` sweeps every kernel against its oracle in
-interpret mode: 1e-3 max abs error bound, observed ~1e-6).
+interpret mode: 1e-3 max abs error bound, observed ~1e-6), and
+``tests/test_chip_compile.py`` compiles each one for a described TPU v5e.
 """
 
 from __future__ import annotations
-
-import warnings
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -45,40 +42,30 @@ from repro.kernels.paged_attention import (
 from repro.kernels.ssd_scan import ssd_decode_step_bh, ssd_scan_bhsp
 
 
-def _auto_impl() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "xla_chunked"
-
-
-# ops that already warned about a compiled-Pallas -> ref fallback (warn once
-# per op per process, not once per step)
-_PALLAS_FALLBACK_WARNED: set[str] = set()
+class PallasUnavailableError(RuntimeError):
+    """``impl="pallas"`` was asked for on a backend with no TPU."""
 
 
 def _resolve_pallas_impl(impl: str, interpret: bool, op: str) -> tuple[str, bool]:
     """Normalize ``impl``/``interpret`` for every Pallas-backed op.
 
-    "pallas_interpret" forces the kernel through the interpreter (works on
-    any backend); plain "pallas" on a non-TPU backend falls back to the
-    ``ref.py`` path with a one-time warning — numerically it IS the oracle,
-    so behavior is identical, just unfused. The policy is uniform across
-    ops so a TPU-tuned launch config (``serve.py --attn-impl pallas``)
-    serves correctly on CPU hosts on ALL paths, including the legacy
-    whole-prompt prefill that lowers through ``flash_attention``.
+    "auto" picks the compiled kernel on TPU and the XLA reference
+    elsewhere; "pallas_interpret" forces the kernel through the interpreter
+    (any backend); plain compiled "pallas" off-TPU raises, uniformly across
+    ops, so a run that asked for the kernels either gets them or stops.
     """
+    on_tpu = jax.default_backend() == "tpu"
+    if impl == "auto":
+        impl = "pallas" if on_tpu else "xla_chunked"
     if impl == "pallas_interpret":
         return "pallas", True
-    if impl == "pallas" and not interpret and jax.default_backend() != "tpu":
-        if op not in _PALLAS_FALLBACK_WARNED:
-            _PALLAS_FALLBACK_WARNED.add(op)
-            warnings.warn(
-                f"{op}: impl='pallas' needs a TPU backend (have "
-                f"{jax.default_backend()!r}); falling back to the XLA "
-                f"reference path (one-time warning; use "
-                f"impl='pallas_interpret' to run the kernel interpreted)",
-                RuntimeWarning,
-                stacklevel=3,
-            )
-        return "xla_chunked", False
+    if impl == "pallas" and not interpret and not on_tpu:
+        raise PallasUnavailableError(
+            f"{op}: impl='pallas' needs a TPU backend, have "
+            f"{jax.default_backend()!r} (use impl='auto' for the XLA "
+            f"reference off-TPU, or 'pallas_interpret' to run the kernel "
+            f"interpreted)"
+        )
     return impl, interpret
 
 
@@ -100,8 +87,6 @@ def flash_attention(
     interpret: bool = False,
 ) -> jax.Array:
     """Multi-head / grouped-query attention. Returns (B, Sq, H, D)."""
-    if impl == "auto":
-        impl = _auto_impl()
     impl, interpret = _resolve_pallas_impl(impl, interpret, "flash_attention")
     if impl == "naive":
         return ref.flash_attention_ref(q, k, v, causal=causal, scale=scale)
@@ -143,7 +128,7 @@ def paged_attention(
 
     Quantized pools: when ``k_scale``/``v_scale`` are given the pages are
     int8 with one f32 scale per (page, position, kv head). The Pallas path
-    fuses dequant into the page load; the XLA fallback dequantizes the pool
+    fuses dequant into the page load; the XLA path dequantizes the pool
     through :func:`ref.dequantize_pages` and runs the unchanged fp32 oracle,
     so both lowerings share one ground truth.
 
@@ -156,8 +141,6 @@ def paged_attention(
     (Pallas and the XLA refs) works unchanged on the slice; the q/kv head
     ratio must survive the slicing, which the divisibility check asserts.
     """
-    if impl == "auto":
-        impl = _auto_impl()
     impl, interpret = _resolve_pallas_impl(impl, interpret, "paged_attention")
     b, h, d = q.shape
     kvh = k_pages.shape[2]
@@ -209,8 +192,6 @@ def paged_prefill_attention(
     block table replicated — and ``ref.paged_prefill_attention_ref`` stays
     the oracle and the CPU path.
     """
-    if impl == "auto":
-        impl = _auto_impl()
     impl, interpret = _resolve_pallas_impl(
         impl, interpret, "paged_prefill_attention"
     )
@@ -269,7 +250,7 @@ def paged_mixed_attention(
     ``num_decode`` is an OPTIONAL static structure hint: when set, the
     caller asserts rows ``[num_decode, R)`` form one prefill chunk — every
     row repeats the same block-table row, live rows hold contiguous
-    positions ``start + i`` and dead rows are a suffix. The XLA fallback
+    positions ``start + i`` and dead rows are a suffix. The XLA path
     then evaluates decode rows through :func:`ref.paged_attention_ref` and
     chunk rows through :func:`ref.paged_prefill_attention_ref`, gathering
     the chunk's K/V ONCE instead of once per chunk row (the generic ref
@@ -278,8 +259,6 @@ def paged_mixed_attention(
     the hint; the generic ref stays the oracle the fuzz harness compares
     both lowerings against.
     """
-    if impl == "auto":
-        impl = _auto_impl()
     impl, interpret = _resolve_pallas_impl(
         impl, interpret, "paged_mixed_attention"
     )
@@ -350,8 +329,6 @@ def ssd_scan(
     contribution — y_t += C_t·(e^{Σ≤t dA} h0), fs += e^{Σ dA} h0 — is
     superposed in closed form on top of the kernel output.
     """
-    if impl == "auto":
-        impl = _auto_impl()
     impl, interpret = _resolve_pallas_impl(impl, interpret, "ssd_scan")
     if impl == "naive":
         return ref.ssd_sequential(x, dt, A, Bm, Cm, init_state=init_state)
@@ -401,8 +378,6 @@ def ssd_decode_step(
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Single-token SSD recurrence. Returns (y (B,H,P), new_state f32)."""
-    if impl == "auto":
-        impl = _auto_impl()
     impl, interpret = _resolve_pallas_impl(impl, interpret, "ssd_decode_step")
     if impl in ("naive", "xla_chunked"):
         return ref.ssd_decode_step(state, x_t, dt_t, A, B_t, C_t)

@@ -134,13 +134,12 @@ def main() -> int:
                     help="paged engine: attention lowering for decode and "
                          "chunked prefill — 'auto' uses the Pallas kernels "
                          "on TPU and the XLA reference elsewhere; 'pallas' "
-                         "on a non-TPU backend falls back to the reference "
-                         "with a one-time warning")
+                         "on a non-TPU backend raises")
     ap.add_argument("--ssd-impl", default="auto",
                     choices=["auto", "pallas", "pallas_interpret",
                              "xla_chunked", "naive"],
                     help="ssm engine: SSD scan/decode lowering — same "
-                         "auto/fallback contract as --attn-impl")
+                         "auto/pallas contract as --attn-impl")
     ap.add_argument("--fleet", type=int, default=0, metavar="N",
                     help="run the supervised fleet instead of the flat "
                          "worker pool: a FleetSupervisor with N initial "
@@ -157,6 +156,9 @@ def main() -> int:
     ap.add_argument("--workdir", default="experiments/serve_run")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro.configs import get_arch, reduced
     from repro.core import TopicBus
     from repro.core.autoscaler import Autoscaler, AutoscalerConfig
@@ -177,7 +179,7 @@ def main() -> int:
     )
     from repro.serving.executor import (
         default_serving_mesh,
-        place_serving_params,
+        init_serving_params,
         set_default_serving_mesh,
     )
     from repro.serving.metrics import UtilizationMetrics
@@ -209,14 +211,14 @@ def main() -> int:
     events = EventLog(bus, workflow=f"serve-{cfg.name}")
     registry = ServiceRegistry(bus)
 
-    model = build_model(cfg)
-    params = model.init(jax.random.key(0))
     if sharded:
         # validates the mesh ONCE in the main thread (a bad --mesh N fails
-        # fast here, not inside every worker) and pre-shards the weights so
-        # all workers share one placed copy instead of each materializing
-        # their own
-        params = place_serving_params(cfg, params)
+        # fast here, not inside every worker) and creates the weights in
+        # their shardings, so no chip ever holds the whole model and all
+        # workers share one placed copy
+        params = init_serving_params(cfg, jax.random.key(0))
+    else:
+        params = build_model(cfg).init(jax.random.key(0))
     shared = list(range(2, 2 + args.shared_prefix))
     max_len = 64 + args.shared_prefix + args.max_new
 
@@ -287,6 +289,7 @@ def main() -> int:
         return _run_fleet(args, bus, events, make_engine)
 
     done: dict[str, list[int]] = {}
+    failures: list[tuple[int, BaseException]] = []  # (worker id, error)
     latencies: list = []  # Results, for TTFT/ITL percentiles
     utilization = UtilizationMetrics()  # merged across workers
     lock = threading.Lock()
@@ -306,20 +309,28 @@ def main() -> int:
 
     def worker(wid: int, stop: threading.Event):
         """THE worker loop: engine-agnostic, protocol-driven, streaming."""
-        engine = make_engine()
-        registry.register("generate", f"pod://server-{wid}", f"server-{wid}")
-        handles = {}
+        engine = None
         try:
-            _worker_loop(engine, stop, handles)
-        finally:
-            cache = getattr(engine, "cache", None)
-            if cache is not None and getattr(cache, "tiers", None) is not None:
-                # drain parked prefixes to host/persist so a --persist-dir
-                # rerun of the same prompts revives them across restarts
-                cache.flush_tiers()
-                engine._record_tiers()  # fold the flush into the gauges
+            engine = make_engine()
+            registry.register("generate", f"pod://server-{wid}",
+                              f"server-{wid}")
+            _worker_loop(engine, stop, {})
+        except BaseException as e:
+            # a worker that raised (a compile error, a device fault) would
+            # raise again if replaced: stop the whole run instead
             with lock:
-                utilization.merge(engine.utilization)
+                failures.append((wid, e))
+            stop.set()
+        finally:
+            if engine is not None:
+                cache = getattr(engine, "cache", None)
+                if getattr(cache, "tiers", None) is not None:
+                    # drain parked prefixes to host/persist so a
+                    # --persist-dir rerun of the same prompts revives them
+                    cache.flush_tiers()
+                    engine._record_tiers()  # fold the flush into the gauges
+                with lock:
+                    utilization.merge(engine.utilization)
 
     def _worker_loop(engine, stop, handles):
         while not stop.is_set():
@@ -363,7 +374,8 @@ def main() -> int:
     stop = threading.Event()
     t0 = time.time()
     desired, _ = scaler.observe()
-    while len(done) < args.requests and time.time() - t0 < 600:
+    while (len(done) < args.requests and not failures
+           and time.time() - t0 < 600):
         desired, changed = scaler.observe()
         while len([t for t in threads if t.is_alive()]) < desired:
             wid = len(threads)
@@ -374,6 +386,11 @@ def main() -> int:
     stop.set()
     for t in threads:
         t.join(timeout=5)
+    if failures:
+        wid, err = failures[0]
+        raise RuntimeError(
+            f"serving worker server-{wid} failed; run stopped"
+        ) from err
 
     wall = time.time() - t0
     print(f"served {len(done)}/{args.requests} requests in {wall:.1f}s "

@@ -155,6 +155,9 @@ def main() -> int:
     ap.add_argument("--workdir", default="experiments/train_run")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro.core import ArtifactStore, TopicBus, WorkflowScheduler
     from repro.core.faults import FaultInjector, KillRule
     from repro.core.scheduler import RetryPolicy

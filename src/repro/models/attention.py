@@ -31,12 +31,17 @@ def attn_param_specs(cfg: ModelConfig, stacked: int | None = None) -> dict:
     pre = (stacked,) if stacked else ()
     pax = ("stack",) if stacked else ()
     wo_init = "zeros" if cfg.num_heads_padded else "normal"
+    # fan-in is d for q/k/v and h*hd for o: the shape rule (second-to-last
+    # axis) would read h or hd here and blow up attention logits at init
     specs = {
-        "wq": ParamSpec(pre + (d, h, hd), pax + ("embed", "heads", "head_dim")),
-        "wk": ParamSpec(pre + (d, kvh, hd), pax + ("embed", "kv_heads", "head_dim")),
-        "wv": ParamSpec(pre + (d, kvh, hd), pax + ("embed", "kv_heads", "head_dim")),
+        "wq": ParamSpec(pre + (d, h, hd), pax + ("embed", "heads", "head_dim"),
+                        scale=d ** -0.5),
+        "wk": ParamSpec(pre + (d, kvh, hd), pax + ("embed", "kv_heads", "head_dim"),
+                        scale=d ** -0.5),
+        "wv": ParamSpec(pre + (d, kvh, hd), pax + ("embed", "kv_heads", "head_dim"),
+                        scale=d ** -0.5),
         "wo": ParamSpec(pre + (h, hd, d), pax + ("heads", "head_dim", "embed"),
-                        init=wo_init),
+                        init=wo_init, scale=(h * hd) ** -0.5),
     }
     if cfg.qk_norm:
         specs["q_norm"] = ParamSpec(pre + (hd,), pax + (None,), init="ones")

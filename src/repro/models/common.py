@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import tree_leaves_with_path
 from repro.parallel.collectives import psum_tp
 
 
@@ -27,7 +26,7 @@ from repro.parallel.collectives import psum_tp
 class ParamSpec:
     shape: tuple[int, ...]
     axes: tuple[str | None, ...]
-    init: str = "normal"  # normal | zeros | ones | embed
+    init: str = "normal"  # normal | zeros | ones | embed | ssm_a_log | ssm_dt_bias
     scale: float | None = None
     dtype: str | None = None  # None -> model default
 
@@ -49,7 +48,7 @@ def init_params(specs: Any, key: jax.Array, default_dtype: str) -> Any:
     RNG is folded per tree-path so adding a parameter never reshuffles the
     others (checkpoint/elastic stability).
     """
-    leaves = tree_leaves_with_path(specs, is_leaf=_is_spec)
+    leaves = jax.tree.leaves_with_path(specs, is_leaf=_is_spec)
 
     def one(path, spec: ParamSpec):
         dt = _leaf_dtype(spec, default_dtype)
@@ -60,6 +59,17 @@ def init_params(specs: Any, key: jax.Array, default_dtype: str) -> Any:
         # deterministic across processes (hash() is PYTHONHASHSEED-random)
         seed = zlib.crc32(jax.tree_util.keystr(path).encode()) % (2**31)
         k = jax.random.fold_in(key, seed)
+        if spec.init == "ssm_a_log":
+            # Mamba2's decay range: A ~ U[1, 16], stored as log A
+            a = jax.random.uniform(k, spec.shape, jnp.float32, 1.0, 16.0)
+            return jnp.log(a).astype(dt)
+        if spec.init == "ssm_dt_bias":
+            # Mamba2's step range: dt log-uniform in [1e-3, 1e-1], stored as
+            # softplus^-1(dt); a unit-scale bias instead makes steps ~0.7
+            # and the stack too sensitive to bf16 rounding to compare paths
+            lo, hi = np.log(1e-3), np.log(1e-1)
+            step = jnp.exp(lo + (hi - lo) * jax.random.uniform(k, spec.shape))
+            return (step + jnp.log(-jnp.expm1(-step))).astype(dt)
         if spec.init == "embed":
             scale = spec.scale if spec.scale is not None else 1.0
         else:

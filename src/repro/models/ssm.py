@@ -32,9 +32,10 @@ def mamba_param_specs(cfg: ModelConfig, stacked: int | None = None) -> dict:
         "conv_x": ParamSpec(pre + (w, din), pax + (None, "ff"), scale=0.5),
         "conv_b": ParamSpec(pre + (w, n), pax + (None, None), scale=0.5),
         "conv_c": ParamSpec(pre + (w, n), pax + (None, None), scale=0.5),
-        "a_log": ParamSpec(pre + (h,), pax + ("ssm_heads",), init="ones"),
+        "a_log": ParamSpec(pre + (h,), pax + ("ssm_heads",), init="ssm_a_log"),
         "d_skip": ParamSpec(pre + (h,), pax + ("ssm_heads",), init="ones"),
-        "dt_bias": ParamSpec(pre + (h,), pax + ("ssm_heads",), init="zeros"),
+        "dt_bias": ParamSpec(pre + (h,), pax + ("ssm_heads",),
+                             init="ssm_dt_bias"),
         "norm": ParamSpec(pre + (din,), pax + ("ff",), init="ones"),
         "w_out": ParamSpec(pre + (din, d), pax + ("ff", "embed")),
     }

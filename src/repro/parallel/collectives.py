@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 
 # ---------------------------------------------------------------------------
 # tensor-parallel context (serving executor)
@@ -159,7 +158,7 @@ def make_compressed_grad_allreduce(mesh: Mesh, axis: str = "data"):
         return mean.astype(g.dtype), new_carry
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), P(axis)),
         out_specs=(P(), P(axis)),

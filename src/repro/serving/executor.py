@@ -44,7 +44,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map_unchecked
 from repro.launch.mesh import make_serving_mesh
 from repro.models import build_model
 from repro.models.common import sample_tokens
@@ -57,8 +56,8 @@ from repro.serving.scheduler import DecodeInputs, PrefillChunk, StepPlan
 __all__ = [
     "ModelExecutor",
     "default_serving_mesh",
+    "init_serving_params",
     "pick_tp",
-    "place_serving_params",
     "serving_mesh_scope",
     "set_default_serving_mesh",
     "validate_serving_mesh",
@@ -173,25 +172,26 @@ def _serving_param_specs(model, mesh: Mesh, vocab_sharded: bool):
     return specs
 
 
-def place_serving_params(cfg, params, mesh: Mesh | None = None):
-    """Shard a params tree for the serving mesh ONCE, up front.
+def init_serving_params(cfg, key, mesh: Mesh | None = None):
+    """Initialize a params tree directly in its serving shardings.
 
-    Multi-worker drivers (``launch/serve.py``) call this before spawning
-    engines: every :class:`ModelExecutor` built from the returned tree sees
-    leaves already carrying the target sharding, and its own ``device_put``
-    is then a no-op — all workers share ONE placed copy instead of each
-    materializing its own.
+    ``jit`` of the model's ``init`` with the serving TP specs as
+    ``out_shardings``: every shard is created on its own device, so a model
+    whose weights do not fit one chip is never materialized whole on the
+    first device. Multi-worker drivers (``launch/serve.py``) call this
+    before spawning engines: every :class:`ModelExecutor` built from the
+    returned tree sees leaves already carrying the target sharding, and its
+    own ``device_put`` is then a no-op — all workers share ONE placed copy.
     """
     mesh = mesh if mesh is not None else default_serving_mesh(cfg)
     tp = validate_serving_mesh(cfg, mesh)
-    if tp == 1:
-        return params
-    vocab_sharded = not cfg.tie_embeddings
-    specs = _serving_param_specs(build_model(cfg), mesh, vocab_sharded)
-    return jax.tree.map(
-        lambda arr, spec: jax.device_put(arr, NamedSharding(mesh, spec)),
-        params, specs,
+    model = build_model(cfg)
+    specs = _serving_param_specs(model, mesh, (not cfg.tie_embeddings) and tp > 1)
+    shardings = jax.tree.map(
+        lambda spec: NamedSharding(mesh, spec), specs,
+        is_leaf=lambda v: isinstance(v, P),
     )
+    return jax.jit(model.init, out_shardings=shardings)(key)
 
 
 class ModelExecutor:
@@ -243,7 +243,7 @@ class ModelExecutor:
     def _place(self, params):
         """Shard params + page pool onto the mesh (no-op layout on 1 dev).
 
-        When the caller pre-placed the tree (:func:`place_serving_params`,
+        When the caller pre-placed the tree (:func:`init_serving_params`,
         the multi-worker path) every ``device_put`` here no-ops and all
         executors share one device copy of the weights."""
         if self.tp == 1:
@@ -267,8 +267,11 @@ class ModelExecutor:
         }
 
     def _smap(self, fn, in_specs, out_specs):
-        return shard_map_unchecked(
-            fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs
+        # replication checking off: scatters and gathered logits are
+        # replicated by construction, which the checker cannot prove
+        return jax.shard_map(
+            fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
         )
 
     def _tp_ctx(self):

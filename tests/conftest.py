@@ -1,47 +1,8 @@
-import sys
-import types
-
 import numpy as np
 import pytest
 
 # NOTE: no XLA_FLAGS here on purpose — tests run with the real (1) CPU
 # device; only launch/dryrun.py forces 512 placeholder devices.
-
-# ---------------------------------------------------------------------------
-# hypothesis is optional: on minimal installs the property tests skip instead
-# of breaking collection of every module that imports it.
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ImportError:  # pragma: no cover - depends on installed extras
-    def _given(*_args, **_kwargs):
-        def deco(fn):
-            def skipper(*args, **kwargs):
-                pytest.skip("hypothesis not installed")
-
-            skipper.__name__ = fn.__name__
-            skipper.__doc__ = fn.__doc__
-            return skipper
-
-        return deco
-
-    def _settings(*_args, **_kwargs):
-        return lambda fn: fn
-
-    def _strategy(*_args, **_kwargs):
-        return None
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.__getattr__ = lambda name: _strategy  # any strategy constructor
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    _hyp.__getattr__ = lambda name: _strategy
-
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
 
 
 @pytest.fixture

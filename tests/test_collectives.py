@@ -62,9 +62,7 @@ def test_compressed_psum_close_to_exact(rng):
         from functools import partial
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
-
-        @partial(shard_map, mesh=mesh, in_specs=P("data"), out_specs=P())
+        @partial(jax.shard_map, mesh=mesh, in_specs=P("data"), out_specs=P())
         def f(x):
             return compressed_psum(x[0], "data")
 

@@ -9,13 +9,10 @@ GQA/MQA groupings — and asserts kernel-vs-oracle parity ≤ 1e-3 (the repo
 contract from ``ops.py``), reporting the exact failing parameter tuple on
 mismatch so a regression reproduces with one ``pytest -k`` invocation.
 
-Sweeps are a deterministic seeded grid (always run) plus a hypothesis
-property pass (skipped when hypothesis is not installed — see
-``conftest.py``). CI runs this file in the dedicated interpret-mode kernel
+Sweeps are a deterministic seeded grid plus a hypothesis property pass.
+CI runs this file in the dedicated interpret-mode kernel
 job next to ``test_kernels.py``.
 """
-
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -736,45 +733,48 @@ def test_quantize_dequant_roundtrip_bound(rows, kvh, d, scale_exp, seed):
 
 
 # ---------------------------------------------------------------------------
-# non-TPU fallback policy (ops.paged_* with impl="pallas")
+# off-TPU policy: compiled impl="pallas" raises, for every op
 # ---------------------------------------------------------------------------
 
 
-def test_pallas_fallback_warns_once_and_matches_ref():
-    """On a non-TPU backend ``impl='pallas'`` must serve through the ref
-    path — numerically identical — after ONE RuntimeWarning per op."""
-    if jax.default_backend() == "tpu":
-        pytest.skip("fallback only exists off-TPU")
+def _pallas_off_tpu_calls():
+    """One small call per Pallas-backed op, keyed by op name."""
     q, kp, vp, bt, start, valid = _prefill_case((4, 4, 4, 4, 2, 16, 8, 1), 0)
-    ops._PALLAS_FALLBACK_WARNED.discard("paged_prefill_attention")
-    with pytest.warns(RuntimeWarning, match="falling back"):
-        got = ops.paged_prefill_attention(q, kp, vp, bt, start, valid,
-                                          impl="pallas")
-    want = ref.paged_prefill_attention_ref(q, kp, vp, bt, start, valid)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")  # second call: silent
-        ops.paged_prefill_attention(q, kp, vp, bt, start, valid, impl="pallas")
-
     qd, kpd, vpd, btd, lens = _decode_case((2, 4, 2, 16, 8, 2, False), 0)
-    ops._PALLAS_FALLBACK_WARNED.discard("paged_attention")
-    with pytest.warns(RuntimeWarning, match="falling back"):
-        got = ops.paged_attention(qd, kpd, vpd, btd, lens, impl="pallas")
-    want = ref.paged_attention_ref(qd, kpd, vpd, btd, lens)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        ops.paged_attention(qd, kpd, vpd, btd, lens, impl="pallas")
+    qm, kpm, vpm, btm, last = _mixed_case((4, 4, 2, 8, 4, 3, 1, 2), 0)
+    x = jnp.ones((1, 16, 2, 8), jnp.float32)
+    dt = jnp.full((1, 16, 2), 0.1, jnp.float32)
+    a = -jnp.ones((2,), jnp.float32)
+    bm = jnp.ones((1, 16, 8), jnp.float32)
+    fq = jnp.ones((1, 16, 2, 8), jnp.float32)
+    return {
+        "flash_attention": lambda: ops.flash_attention(
+            fq, fq, fq, impl="pallas"),
+        "paged_attention": lambda: ops.paged_attention(
+            qd, kpd, vpd, btd, lens, impl="pallas"),
+        "paged_prefill_attention": lambda: ops.paged_prefill_attention(
+            q, kp, vp, bt, start, valid, impl="pallas"),
+        "paged_mixed_attention": lambda: ops.paged_mixed_attention(
+            qm, kpm, vpm, btm, last, impl="pallas"),
+        "ssd_scan": lambda: ops.ssd_scan(x, dt, a, bm, bm, impl="pallas"),
+        "ssd_decode_step": lambda: ops.ssd_decode_step(
+            *_ssd_decode_case((2, 4, 16, 32), 0), impl="pallas"),
+    }
 
-    # the serving decode hot-path op must obey the same policy: off-TPU
-    # impl='pallas' pins to ref.ssd_decode_step bit-for-bit after one warning
-    dargs = _ssd_decode_case((2, 4, 16, 32), 0)
-    ops._PALLAS_FALLBACK_WARNED.discard("ssd_decode_step")
-    with pytest.warns(RuntimeWarning, match="falling back"):
-        y_got, st_got = ops.ssd_decode_step(*dargs, impl="pallas")
-    y_want, st_want = ref.ssd_decode_step(*dargs)
-    np.testing.assert_array_equal(np.asarray(y_got), np.asarray(y_want))
-    np.testing.assert_array_equal(np.asarray(st_got), np.asarray(st_want))
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        ops.ssd_decode_step(*dargs, impl="pallas")
+
+@pytest.mark.parametrize("op", [
+    "flash_attention", "paged_attention", "paged_prefill_attention",
+    "paged_mixed_attention", "ssd_scan", "ssd_decode_step",
+])
+def test_pallas_off_tpu_raises(op):
+    """Off-TPU, ``impl='pallas'`` must raise for every op — never serve
+    through the reference in silence — while "auto" keeps resolving to the
+    XLA path there."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the raise only exists off-TPU")
+    call = _pallas_off_tpu_calls()[op]
+    with pytest.raises(ops.PallasUnavailableError, match=op):
+        call()
+    assert ops._resolve_pallas_impl("auto", False, op) == ("xla_chunked", False)
+    assert ops._resolve_pallas_impl("pallas_interpret", False, op) == (
+        "pallas", True)

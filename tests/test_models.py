@@ -12,7 +12,6 @@ import pytest
 
 from repro.configs import ARCHS, describe, reduced
 from repro.configs.base import ShapeConfig
-from repro.compat import tree_leaves_with_path
 from repro.models import build_model
 from repro.models.api import make_batch
 from repro.models.lm import chunked_cross_entropy, padded_vocab
@@ -33,7 +32,7 @@ def test_arch_smoke_train_step(name):
     assert np.isfinite(float(metrics["ce"]))
     # gradients exist and are finite for every leaf
     grads = jax.grad(lambda p: model.loss_fn(p, batch)[0])(params)
-    for path, g in tree_leaves_with_path(grads):
+    for path, g in jax.tree.leaves_with_path(grads):
         assert np.isfinite(np.asarray(g)).all(), (name, path)
 
 

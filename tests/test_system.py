@@ -140,3 +140,55 @@ def test_serving_api_surface_matches_snapshot():
         "public serving surface drifted from tools/serving_api.txt; "
         "run tools/check_api.py --update and review the diff"
     )
+
+
+def test_compile_cache_placed_from_env_or_fixed_in_repo(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and nothing is set in code;
+    otherwise the cache goes to the one fixed, git-ignored directory in the
+    checkout."""
+    import jax
+
+    from repro.launch import compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
+        assert compile_cache.enable_compile_cache() == "/placed/outside"
+        assert jax.config.jax_compilation_cache_dir == prev
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = Path(__file__).resolve().parent.parent
+        want = str(repo / ".jax_cache")
+        assert compile_cache.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_serve_stops_at_once_when_a_worker_fails(tmp_path, monkeypatch):
+    """A worker that raises (here: compiled Pallas asked for off-TPU) ends
+    the serving run with an error instead of being replaced by workers
+    that would fail the same way until the run's 600 s deadline."""
+    import sys
+    import time
+
+    import jax
+
+    from repro.kernels.ops import PallasUnavailableError
+    from repro.launch import serve
+
+    if jax.default_backend() == "tpu":
+        pytest.skip("compiled Pallas only fails off-TPU")
+    # placed from outside: the helper then sets no cache in this process
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--arch", "smollm-360m", "--reduced", "--requests", "4",
+        "--max-new", "2", "--attn-impl", "pallas",
+        "--workdir", str(tmp_path / "run"),
+    ])
+    t0 = time.time()
+    with pytest.raises(RuntimeError, match="run stopped") as err:
+        serve.main()
+    assert isinstance(err.value.__cause__, PallasUnavailableError)
+    assert time.time() - t0 < 120
