@@ -1,0 +1,15 @@
+"""Executor and host, closed-loop cells: mean over the traced engine steps
+(``bench.step`` spans) of the step's length less the device time inside
+it: the host's scheduling, packing, dispatch and synchronisation per step."""
+
+NAME, UNIT = "host_ms_per_step.batch", "ms"
+
+
+def read(ctx):
+    if ctx.loop != "closed" or ctx.summary is None:
+        return None
+    dur = ctx.summary.span_ns.get("bench.step", [])
+    busy = ctx.summary.span_busy_ns.get("bench.step", [])
+    if not dur:
+        return None
+    return sum(d - b for d, b in zip(dur, busy)) / len(dur) / 1e6

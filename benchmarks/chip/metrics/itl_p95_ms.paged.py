@@ -1,0 +1,16 @@
+"""95th percentile of all inter-token gaps delivered in the window in
+the paged engine's cells, on the benchmark's clock as each engine step
+returned its tokens."""
+
+import numpy as np
+
+NAME, UNIT = "itl_p95_ms.paged", "ms"
+
+
+def read(ctx):
+    if ctx.kind != "paged":
+        return None
+    gaps = [g for t, g in ctx.gaps if ctx.origin <= t < ctx.window_end]
+    if not gaps:
+        return None
+    return float(np.percentile(gaps, 95) * 1e3)
