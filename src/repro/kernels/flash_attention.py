@@ -138,4 +138,5 @@ def flash_attention_bhsd(
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

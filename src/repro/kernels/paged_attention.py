@@ -150,11 +150,12 @@ def _split_rest(rest, quant: bool):
 
 
 def _paged_call(kernel, prefetch, q, k_pages, v_pages, k_scale, v_scale, *,
-                grid, q_map, page_map, interpret: bool):
+                name: str, grid, q_map, page_map, interpret: bool):
     """Shared ``pallas_call`` plumbing of the three kernels: q/out blocks of
     ``(1, KVH, rows, D)``, all-head page (and scale) blocks at the physical
     page ``page_map`` reads from the prefetched table, and the per-head
-    online-softmax scratch."""
+    online-softmax scratch. ``name`` names the kernel's custom call in the
+    compiled HLO, and so in a profiler trace."""
     _, kvh, rows, d = q.shape
     page_size = k_pages.shape[1]
     page_spec = pl.BlockSpec((1, page_size, kvh, d), page_map)
@@ -183,6 +184,7 @@ def _paged_call(kernel, prefetch, q, k_pages, v_pages, k_scale, v_scale, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name=name,
     )(*prefetch, *operands)
 
 
@@ -242,7 +244,7 @@ def paged_attention_bkgd(
     )
     return _paged_call(
         kernel, (block_tables, lengths), q, k_pages, v_pages,
-        k_scale, v_scale, grid=(b, mp),
+        k_scale, v_scale, name="paged_decode_attention", grid=(b, mp),
         q_map=lambda b_, p_, bt, ln: (b_, 0, 0, 0),
         # physical page comes from the prefetched block table
         page_map=lambda b_, p_, bt, ln: (bt[b_, p_], 0, 0, 0),
@@ -316,7 +318,7 @@ def paged_prefill_attention_ckgd(
     )
     out = _paged_call(
         kernel, (block_table, meta), qf, k_pages, v_pages,
-        k_scale, v_scale, grid=(mp,),
+        k_scale, v_scale, name="paged_prefill_attention", grid=(mp,),
         q_map=lambda p_, bt, mt: (0, 0, 0, 0),
         page_map=lambda p_, bt, mt: (bt[p_], 0, 0, 0),
         interpret=interpret,
@@ -381,7 +383,7 @@ def paged_mixed_attention_rkgd(
     )
     return _paged_call(
         kernel, (block_tables, last_pos), q, k_pages, v_pages,
-        k_scale, v_scale, grid=(r, mp),
+        k_scale, v_scale, name="paged_mixed_attention", grid=(r, mp),
         q_map=lambda r_, p_, bt, lp: (r_, 0, 0, 0),
         # physical page comes from the row's prefetched block table
         page_map=lambda r_, p_, bt, lp: (bt[r_, p_], 0, 0, 0),

@@ -136,6 +136,7 @@ def ssd_scan_bhsp(
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        name="ssd_chunk_scan",
     )(x, dt.reshape(b, h, nc, 1, chunk), A.astype(jnp.float32), Bm, Cm)
     return y, fs
 
@@ -193,6 +194,7 @@ def ssd_decode_step_bh(
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         interpret=interpret,
+        name="ssd_decode_step",
     )(state, x_t[..., None], dt_t.astype(jnp.float32), A.astype(jnp.float32),
       B_t[:, None], C_t[:, None])
     return y[..., 0], ns

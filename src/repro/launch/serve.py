@@ -35,10 +35,12 @@ The paged engine's executor runs under ``shard_map`` on a ``("model",)``
 mesh; ``--mesh auto`` (default) picks the largest tensor-parallel degree
 the model's head counts allow over the local devices, ``--mesh N`` forces
 an explicit size (1 disables sharding). The run prints p50/p90/p99
-time-to-first-token and inter-token latency plus the per-step decode-slot
-occupancy and page-pool utilization gauges. The HPA analogue watches
-consumer lag and scales workers in [min,max]. CPU-runnable with reduced
-configs:
+time-to-first-token and inter-token latency, p50/p95 queue wait (submit
+to slot) and prefill wait (slot to first chunk), the per-step decode-slot
+occupancy and page-pool utilization gauges, and the ms per engine step
+of each step phase (admit, plan, pack, dispatch, sync, deliver). The HPA
+analogue watches consumer lag and scales workers in [min,max].
+CPU-runnable with reduced configs:
 
   PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m --reduced \
       --requests 24 --shared-prefix 32
@@ -175,6 +177,7 @@ def main() -> int:
         SSMEngine,
         UnsupportedConfigError,
         format_latency,
+        format_waits,
         request_from_message,
     )
     from repro.serving.executor import (
@@ -401,7 +404,9 @@ def main() -> int:
     summary = format_latency(latencies)
     if summary != "no_latency_data":
         print(summary)
+        print(format_waits(latencies))
     print("utilization:", utilization.format())
+    print("step phases (ms per step):", utilization.format_phases())
     autoscales = events.history("autoscale")
     print("autoscale events:", [(e["old"], e["new"]) for e in autoscales])
     assert len(done) == args.requests

@@ -44,7 +44,12 @@ from repro.serving.fleet import (
 )
 from repro.serving.kv_cache import PagedKVCache, PagePool
 from repro.serving.kv_tiers import KVTierManager
-from repro.serving.metrics import FleetMetrics, format_latency, latency_percentiles
+from repro.serving.metrics import (
+    FleetMetrics,
+    format_latency,
+    format_waits,
+    latency_percentiles,
+)
 from repro.serving.speculative import (
     DraftModelProposer,
     NgramProposer,
@@ -83,6 +88,7 @@ __all__ = [
     "build_proposer",
     "fleet_seed",
     "format_latency",
+    "format_waits",
     "latency_percentiles",
     "request_from_message",
 ]

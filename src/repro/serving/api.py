@@ -151,6 +151,8 @@ class Result:
     itl: list[float] = field(default_factory=list)  # inter-token gaps (s)
     finish_reason: FinishReason | None = None
     error: str | None = None
+    queue_wait: float | None = None    # seconds, submitted -> admitted
+    prefill_wait: float | None = None  # seconds, admitted -> prefill_start
 
 
 @dataclass(frozen=True)
@@ -183,6 +185,13 @@ class RequestHandle:
     ``done``. Preemption is transparent: regenerated tokens are de-duplicated
     against what was already streamed (sampling is keyed off
     ``(seed, token_index)``, so a regenerated stream is identical).
+
+    Lifecycle stamps, on ``time.perf_counter`` like ``arrival``: when the
+    engine accepted the request (``submitted``; ``arrival`` stays the
+    caller's due time), when it first got a slot (``admitted``) and when
+    its first prefill chunk was dispatched (``prefill_start``; after a
+    prefix hit, the first novel chunk). Each is set once: re-admission
+    after a preemption keeps the first.
     """
 
     def __init__(self, request: Request, engine: "EngineBase | None" = None):
@@ -194,6 +203,9 @@ class RequestHandle:
         self.finish_reason: FinishReason | None = None
         self.error: str | None = None
         self.arrival: float | None = None
+        self.submitted: float | None = None
+        self.admitted: float | None = None
+        self.prefill_start: float | None = None
         self.seed: int = 0           # effective sampling seed (engine-set)
         self.preemptions: int = 0
         self._engine = engine
@@ -204,6 +216,11 @@ class RequestHandle:
     def done(self) -> bool:
         return self.finish_reason is not None
 
+    @property
+    def engine(self) -> "EngineBase | None":
+        """The engine serving this request (None for a bare handle)."""
+        return self._engine
+
     def new_tokens(self) -> list[int]:
         """Drain and return the tokens emitted since the last call."""
         out = self.tokens[self._cursor:]
@@ -212,12 +229,17 @@ class RequestHandle:
 
     def cancel(self) -> bool:
         """Cancel this request on its engine (queued or mid-decode)."""
-        return self._engine.cancel(self.uid) if self._engine else False
+        return self.engine.cancel(self.uid) if self.engine else False
 
     def result(self) -> Result:
+        def wait(a, b):
+            return None if a is None or b is None else b - a
+
         return Result(
             self.uid, list(self.tokens), ttft=self.ttft, itl=list(self.itl),
             finish_reason=self.finish_reason, error=self.error,
+            queue_wait=wait(self.submitted, self.admitted),
+            prefill_wait=wait(self.admitted, self.prefill_start),
         )
 
     def _emit(self, tok: int, now: float) -> None:
@@ -507,6 +529,7 @@ class EngineBase:
             self.stats["rejected"] += 1
             return h
         now = time.perf_counter()
+        h.submitted = now
         h.arrival = request.arrival_t if request.arrival_t is not None else now
         self._submit_counter += 1
         sp = request.sampling
@@ -546,6 +569,14 @@ class EngineBase:
         raise NotImplementedError
 
     # -- shared internals ----------------------------------------------
+    @staticmethod
+    def _stamp(h: RequestHandle, stage: str) -> None:
+        """Set ``h``'s ``admitted`` or ``prefill_start`` stamp, once: a
+        re-admission after a preemption keeps the first. Every engine
+        stamps through here."""
+        if getattr(h, stage) is None:
+            setattr(h, stage, time.perf_counter())
+
     def _drain_events(self) -> list[StreamEvent]:
         out, self._events = self._events, []
         return out

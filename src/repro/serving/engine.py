@@ -68,7 +68,7 @@ from repro.core.storage import ArtifactStore
 from repro.serving.executor import ModelExecutor
 from repro.serving.kv_cache import PagedKVCache, cdiv
 from repro.serving.kv_tiers import KVTierManager
-from repro.serving.metrics import UtilizationMetrics
+from repro.serving.metrics import UtilizationMetrics, phase
 from repro.serving.scheduler import Scheduler, Sequence
 from repro.serving.speculative import SPEC_MODES, build_proposer
 
@@ -218,8 +218,11 @@ class GenerationEngine(EngineBase):
             batch["frames"] = jnp.zeros(
                 (b, plen, self.cfg.d_model), jnp.dtype(self.cfg.dtype)
             )
-        cache, logits = self._prefill(self.params, batch)
         rows = [_Row(r, self._handles[r.uid]) for r in reqs]
+        for row in rows:
+            self._stamp(row.handle, "admitted")
+            self._stamp(row.handle, "prefill_start")
+        cache, logits = self._prefill(self.params, batch)
         sp = [r.sampling for r in reqs]
         st = {
             "cache": cache,
@@ -469,34 +472,40 @@ class ContinuousBatchingEngine(EngineBase):
             self._release_slot(slot)
 
     def _admit(self) -> int:
-        now = time.perf_counter()
-        self._expire_queue(now)
-        admitted = 0
-        while True:
-            req = self.admission.peek(now)
-            if req is None or not self.scheduler.can_place(req):
-                break
-            self.admission.pop(now)
-            handle = self._handles[req.uid]
-            slot, seq, _ = self.scheduler.place(req, handle)
-            admitted += 1
-            if not self._chunked:
-                # legacy whole-prompt path (vlm / prefill_chunk=None):
-                # one executor dispatch per admission
-                tok = self.executor.prefill_whole(req, handle.seed, slot)
-                self._first_token(slot, seq, tok)
-        return admitted
+        with phase("admit"):
+            now = time.perf_counter()
+            self._expire_queue(now)
+            admitted = 0
+            while True:
+                req = self.admission.peek(now)
+                if req is None or not self.scheduler.can_place(req):
+                    break
+                self.admission.pop(now)
+                handle = self._handles[req.uid]
+                slot, seq, _ = self.scheduler.place(req, handle)
+                self._stamp(handle, "admitted")
+                admitted += 1
+                if not self._chunked:
+                    # legacy whole-prompt path (vlm / prefill_chunk=None):
+                    # one executor dispatch per admission
+                    self._stamp(handle, "prefill_start")
+                    tok = self.executor.prefill_whole(req, handle.seed, slot)
+                    self._first_token(slot, seq, tok)
+            return admitted
 
     def _prefill_step(self) -> bool:
         """Advance the oldest in-flight prefill by one fixed-size chunk
         (scheduler picks, executor dispatches)."""
-        work = self.scheduler.next_prefill()
+        with phase("plan"):
+            work = self.scheduler.next_prefill()
         if work is None:
             return False
+        self._stamp(work.seq.handle, "prefill_start")
         tok = self.executor.prefill_chunk(work)
-        self.stats["prefill_chunks"] += 1
-        if self.scheduler.complete_chunk(work):
-            self._first_token(work.slot, work.seq, tok)
+        with phase("deliver"):
+            self.stats["prefill_chunks"] += 1
+            if self.scheduler.complete_chunk(work):
+                self._first_token(work.slot, work.seq, tok)
         return True
 
     def _handle_preempted(self, seq: Sequence) -> None:
@@ -533,10 +542,13 @@ class ContinuousBatchingEngine(EngineBase):
         decode slot and (at most) one prefill chunk in a single
         Pallas-backed executor call. ``step_mode="interleaved"`` keeps the
         pre-fusion behavior (one chunk dispatch, then one decode dispatch)
-        for A/B comparison; both modes produce byte-identical streams."""
-        if self.step_mode == "interleaved":
-            return self._step_interleaved()
-        return self._step_fused()
+        for A/B comparison; both modes produce byte-identical streams.
+        The step is a ``serving.step`` span split into phases
+        (``serving/metrics.py``)."""
+        with self.utilization.step():
+            if self.step_mode == "interleaved":
+                return self._step_interleaved()
+            return self._step_fused()
 
     def _record_batch(self, decode_rows: int, prefill_live: int,
                       rows: int, fused: bool) -> None:
@@ -555,11 +567,14 @@ class ContinuousBatchingEngine(EngineBase):
             rows += len(chunk.tokens)
         self._record_batch(n_dec, chunk.valid if chunk else 0, rows,
                            fused=bool(chunk is not None and n_dec))
+        if chunk is not None:
+            self._stamp(chunk.seq.handle, "prefill_start")
         toks, ctok = self.executor.step(plan)
         if chunk is not None:
-            self.stats["prefill_chunks"] += 1
-            if self.scheduler.complete_chunk(chunk):
-                self._first_token(chunk.slot, chunk.seq, ctok)
+            with phase("deliver"):
+                self.stats["prefill_chunks"] += 1
+                if self.scheduler.complete_chunk(chunk):
+                    self._first_token(chunk.slot, chunk.seq, ctok)
         return toks
 
     def _record_tiers(self) -> None:
@@ -641,57 +656,62 @@ class ContinuousBatchingEngine(EngineBase):
         # chunk-only plans back-to-back until a sequence becomes decodable
         # (cold start, post-burst refill)
         while not sched.has_decodable():
-            plan = sched.build_step_plan()
+            with phase("plan"):
+                plan = sched.build_step_plan()
             if plan.chunk is None:
                 return self._drain_events()
             self._dispatch_plan(plan)
             self._admit()
 
-        # every decode row needs a writable page BEFORE the plan captures
-        # block tables (growth/COW dirties them; eviction can also claim
-        # the slot a chunk would have targeted). Speculating slots pre-grow
-        # k extra positions so the verify dispatch never hits a page fault.
-        proposals = (self._propose_bundles()
-                     if self.spec is not None else {})
-        extra = ({s: len(d) for s, d in proposals.items()}
-                 if proposals else None)
-        for seq in sched.ensure_decode_capacity(extra=extra):
-            self._handle_preempted(seq)
-        if not sched.has_decodable():
-            return self._drain_events()  # preemption can empty the decode set
+        with phase("plan"):
+            # every decode row needs a writable page BEFORE the plan
+            # captures block tables (growth/COW dirties them; eviction can
+            # also claim the slot a chunk would have targeted). Speculating
+            # slots pre-grow k extra positions so the verify dispatch never
+            # hits a page fault.
+            proposals = (self._propose_bundles()
+                         if self.spec is not None else {})
+            extra = ({s: len(d) for s, d in proposals.items()}
+                     if proposals else None)
+            for seq in sched.ensure_decode_capacity(extra=extra):
+                self._handle_preempted(seq)
+            if not sched.has_decodable():
+                # preemption can empty the decode set
+                return self._drain_events()
 
-        decoding, slots = sched.occupancy()
-        used, total = sched.page_utilization()
-        self.utilization.record(active=decoding, slots=slots,
-                                pages_used=used, pages_total=total)
-        self._record_tiers()
-        # eviction may have dropped a proposal's sequence — bundle only
-        # slots that still hold the decoding sequence we drafted for
-        bundles = [
-            sched.build_spec_bundle(s, d, self.spec_k + 1)
-            for s, d in sorted(proposals.items())
-            if sched.slots.get(s) is not None
-            and sched.slots[s].phase == "decode"
-        ]
-        plan = sched.build_step_plan(spec=bundles)
+            decoding, slots = sched.occupancy()
+            used, total = sched.page_utilization()
+            self.utilization.record(active=decoding, slots=slots,
+                                    pages_used=used, pages_total=total)
+            self._record_tiers()
+            # eviction may have dropped a proposal's sequence — bundle only
+            # slots that still hold the decoding sequence we drafted for
+            bundles = [
+                sched.build_spec_bundle(s, d, self.spec_k + 1)
+                for s, d in sorted(proposals.items())
+                if sched.slots.get(s) is not None
+                and sched.slots[s].phase == "decode"
+            ]
+            plan = sched.build_step_plan(spec=bundles)
         toks = None
         if plan.decode_slots or plan.chunk is not None:
             toks = self._dispatch_plan(plan)
-        self.stats["decode_steps"] += 1
-        now = time.perf_counter()
-        # harvest exactly the slots the plan dispatched — the chunk slot
-        # may have become decodable mid-step and is NOT in this batch
-        for slot in plan.decode_slots:
-            seq = sched.slots[slot]
-            tok = int(toks[slot])
-            sched.append_decoded(slot, tok)
-            if self._deliver(seq.handle, tok, len(seq.tokens) - 1, now):
-                self._release_slot(slot)
-        # bundled slots step through their verify dispatch instead
-        for bundle in plan.spec or ():
-            self._harvest_bundle(bundle, now)
-        self._record_tiers()  # post-release: captures end-of-life parking
-        return self._drain_events()
+        with phase("deliver"):
+            self.stats["decode_steps"] += 1
+            now = time.perf_counter()
+            # harvest exactly the slots the plan dispatched — the chunk slot
+            # may have become decodable mid-step and is NOT in this batch
+            for slot in plan.decode_slots:
+                seq = sched.slots[slot]
+                tok = int(toks[slot])
+                sched.append_decoded(slot, tok)
+                if self._deliver(seq.handle, tok, len(seq.tokens) - 1, now):
+                    self._release_slot(slot)
+            # bundled slots step through their verify dispatch instead
+            for bundle in plan.spec or ():
+                self._harvest_bundle(bundle, now)
+            self._record_tiers()  # post-release: captures end-of-life parking
+            return self._drain_events()
 
     def _step_interleaved(self) -> list[StreamEvent]:
         """Pre-fusion step: one chunk dispatch interleaved with one decode
@@ -710,25 +730,28 @@ class ContinuousBatchingEngine(EngineBase):
         if not sched.has_decodable():
             return self._drain_events()
 
-        for seq in sched.ensure_decode_capacity():
-            self._handle_preempted(seq)
-        if not sched.has_decodable():
-            return self._drain_events()  # preemption can empty the decode set
+        with phase("plan"):
+            for seq in sched.ensure_decode_capacity():
+                self._handle_preempted(seq)
+            if not sched.has_decodable():
+                # preemption can empty the decode set
+                return self._drain_events()
 
-        decoding, slots = sched.occupancy()
-        used, total = sched.page_utilization()
-        self.utilization.record(active=decoding, slots=slots,
-                                pages_used=used, pages_total=total)
-        self._record_tiers()
-        self._record_batch(decoding, 0, self.max_slots, fused=False)
-        inputs = sched.build_decode_inputs() if sched.dirty else None
+            decoding, slots = sched.occupancy()
+            used, total = sched.page_utilization()
+            self.utilization.record(active=decoding, slots=slots,
+                                    pages_used=used, pages_total=total)
+            self._record_tiers()
+            self._record_batch(decoding, 0, self.max_slots, fused=False)
+            inputs = sched.build_decode_inputs() if sched.dirty else None
         toks = self.executor.decode(inputs)
-        self.stats["decode_steps"] += 1
-        now = time.perf_counter()
-        for slot, seq in sched.decoding():
-            tok = int(toks[slot])
-            sched.append_decoded(slot, tok)
-            if self._deliver(seq.handle, tok, len(seq.tokens) - 1, now):
-                self._release_slot(slot)
-        self._record_tiers()  # post-release: captures end-of-life parking
-        return self._drain_events()
+        with phase("deliver"):
+            self.stats["decode_steps"] += 1
+            now = time.perf_counter()
+            for slot, seq in sched.decoding():
+                tok = int(toks[slot])
+                sched.append_decoded(slot, tok)
+                if self._deliver(seq.handle, tok, len(seq.tokens) - 1, now):
+                    self._release_slot(slot)
+            self._record_tiers()  # post-release: captures end-of-life parking
+            return self._drain_events()

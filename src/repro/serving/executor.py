@@ -51,6 +51,7 @@ from repro.models.lm import padded_vocab
 from repro.parallel.axes import logical_to_spec
 from repro.parallel.collectives import tensor_parallel
 from repro.serving.kv_cache import write_prefill_pages
+from repro.serving.metrics import phase
 from repro.serving.scheduler import DecodeInputs, PrefillChunk, StepPlan
 
 __all__ = [
@@ -202,6 +203,11 @@ class ModelExecutor:
     :class:`~repro.serving.scheduler.DecodeInputs` work items and keeps
     device mirrors of the last decode batch so steady-state steps transfer
     nothing to the device.
+
+    Each call is split into the step phases only the executor can tell
+    apart (``serving/metrics.py``): ``pack`` (host inputs assembled and
+    uploaded), ``dispatch`` (the jitted call, up to its return) and
+    ``sync`` (the blocking readback of the sampled tokens).
     """
 
     def __init__(self, cfg, params, cache, *, max_len: int,
@@ -334,21 +340,21 @@ class ModelExecutor:
         """Mirror a freshly assembled decode batch to the device (two
         transfers: the packed int32 batch and the packed f32 sampling
         params)."""
-        self._greedy_only = inputs.greedy_only
-        bt = inputs.block_tables
-        s, mp = bt.shape
-        di = np.empty((s, mp + self._DI_COLS), np.int32)
-        di[:, :mp] = bt
-        di[:, mp] = inputs.lengths
-        di[:, mp + 1] = inputs.active
-        di[:, mp + 2] = inputs.tokens[:, 0]
-        di[:, mp + 3] = inputs.top_ks
-        di[:, mp + 4] = inputs.seeds
-        di[:, mp + 5] = inputs.idx
-        self._di = jnp.asarray(di)
-        self._df = jnp.asarray(
-            np.stack([inputs.temps, inputs.top_ps], axis=1).astype(np.float32)
-        )
+        with phase("pack"):
+            self._greedy_only = inputs.greedy_only
+            bt = inputs.block_tables
+            s, mp = bt.shape
+            di = np.empty((s, mp + self._DI_COLS), np.int32)
+            di[:, :mp] = bt
+            di[:, mp] = inputs.lengths
+            di[:, mp + 1] = inputs.active
+            di[:, mp + 2] = inputs.tokens[:, 0]
+            di[:, mp + 3] = inputs.top_ks
+            di[:, mp + 4] = inputs.seeds
+            di[:, mp + 5] = inputs.idx
+            self._di = jnp.asarray(di)
+            self._df = jnp.asarray(np.stack(
+                [inputs.temps, inputs.top_ps], axis=1).astype(np.float32))
 
     def decode(self, inputs: DecodeInputs | None = None) -> np.ndarray:
         """Run one decode step. ``inputs`` refreshes the device mirrors
@@ -357,11 +363,13 @@ class ModelExecutor:
         Returns the sampled token per slot, (S,) int32 on the host."""
         if inputs is not None:
             self.refresh(inputs)
-        pages = dict(self.cache.pages)
-        fn = self._decode_fn(self._greedy_only)
-        pages, self._di, toks = fn(self.params, pages, self._di, self._df)
-        self.cache.swap_pages(pages)
-        return np.asarray(toks)
+        with phase("dispatch"):
+            fn = self._decode_fn(self._greedy_only)
+            pages, self._di, toks = fn(self.params, dict(self.cache.pages),
+                                       self._di, self._df)
+            self.cache.swap_pages(pages)
+        with phase("sync"):
+            return np.asarray(toks)
 
     # ------------------------------------------------------------------
     # fused mixed step (decode batch + one prefill chunk, one dispatch)
@@ -370,16 +378,17 @@ class ModelExecutor:
         """Pack one prefill chunk's host state into two transfers:
         ``ci`` (MP+C+4,) int32 = [block-table row | padded tokens | start,
         valid, top_k, seed] and ``cf`` (2,) f32 = [temperature, top_p]."""
-        sp = chunk.seq.request.sampling
-        row = self.cache.block_tables[chunk.slot]
-        mp, c = row.shape[0], chunk.tokens.shape[0]
-        ci = np.empty(mp + c + 4, np.int32)
-        ci[:mp] = row
-        ci[mp:mp + c] = chunk.tokens
-        ci[mp + c:] = (chunk.start, chunk.valid, sp.top_k,
-                       chunk.seq.handle.seed)
-        cf = np.array([sp.temperature, sp.top_p], np.float32)
-        return jnp.asarray(ci), jnp.asarray(cf)
+        with phase("pack"):
+            sp = chunk.seq.request.sampling
+            row = self.cache.block_tables[chunk.slot]
+            mp, c = row.shape[0], chunk.tokens.shape[0]
+            ci = np.empty(mp + c + 4, np.int32)
+            ci[:mp] = row
+            ci[mp:mp + c] = chunk.tokens
+            ci[mp + c:] = (chunk.start, chunk.valid, sp.top_k,
+                           chunk.seq.handle.seed)
+            cf = np.array([sp.temperature, sp.top_p], np.float32)
+            return jnp.asarray(ci), jnp.asarray(cf)
 
     def _mixed_fn(self, greedy_only: bool):
         """ONE dispatch per mixed step: every decode slot AND one prefill
@@ -473,15 +482,17 @@ class ModelExecutor:
             return self.decode(plan.decode), None
         if plan.decode is not None:
             self.refresh(plan.decode)
-        sp = chunk.seq.request.sampling
-        fn = self._mixed_fn(self._greedy_only and sp.temperature <= 0.0)
         ci, cf = self._pack_chunk(chunk)
-        pages = dict(self.cache.pages)
-        pages, self._di, toks, ctok = fn(
-            self.params, pages, self._di, self._df, ci, cf
-        )
-        self.cache.swap_pages(pages)
-        return np.asarray(toks), int(ctok)
+        with phase("dispatch"):
+            sp = chunk.seq.request.sampling
+            fn = self._mixed_fn(self._greedy_only and sp.temperature <= 0.0)
+            pages, self._di, toks, ctok = fn(
+                self.params, dict(self.cache.pages), self._di, self._df,
+                ci, cf,
+            )
+            self.cache.swap_pages(pages)
+        with phase("sync"):
+            return np.asarray(toks), int(ctok)
 
     # ------------------------------------------------------------------
     # speculative verify (one bundle = one fused dispatch)
@@ -551,21 +562,22 @@ class ModelExecutor:
         candidate KV positions; the engine commits the accepted prefix by
         setting the slot's length (rollback = rewind, nothing else)."""
         sp = bundle.seq.request.sampling
-        row = self.cache.block_tables[bundle.slot]
-        mp, w = row.shape[0], bundle.tokens.shape[0]
-        vi = np.empty(mp + w + 5, np.int32)
-        vi[:mp] = row
-        vi[mp:mp + w] = bundle.tokens
-        vi[mp + w:] = (bundle.start, bundle.valid, sp.top_k,
-                       bundle.seq.handle.seed, len(bundle.seq.tokens))
-        vf = np.array([sp.temperature, sp.top_p], np.float32)
-        fn = self._verify_fn(sp.temperature <= 0.0)
-        pages, toks = fn(
-            self.params, dict(self.cache.pages),
-            jnp.asarray(vi), jnp.asarray(vf),
-        )
-        self.cache.swap_pages(pages)
-        return np.asarray(toks)
+        with phase("pack"):
+            row = self.cache.block_tables[bundle.slot]
+            mp, w = row.shape[0], bundle.tokens.shape[0]
+            vi = np.empty(mp + w + 5, np.int32)
+            vi[:mp] = row
+            vi[mp:mp + w] = bundle.tokens
+            vi[mp + w:] = (bundle.start, bundle.valid, sp.top_k,
+                           bundle.seq.handle.seed, len(bundle.seq.tokens))
+            vf = np.array([sp.temperature, sp.top_p], np.float32)
+            vi, vf = jnp.asarray(vi), jnp.asarray(vf)
+        with phase("dispatch"):
+            fn = self._verify_fn(sp.temperature <= 0.0)
+            pages, toks = fn(self.params, dict(self.cache.pages), vi, vf)
+            self.cache.swap_pages(pages)
+        with phase("sync"):
+            return np.asarray(toks)
 
     # ------------------------------------------------------------------
     # chunked prefill
@@ -610,11 +622,13 @@ class ModelExecutor:
         """Dispatch one chunk; returns the sampled first token (meaningful
         only when this was the prompt's final chunk)."""
         ci, cf = self._pack_chunk(work)
-        pages, tok = self._chunk_prefill_fn()(
-            self.params, dict(self.cache.pages), ci, cf
-        )
-        self.cache.swap_pages(pages)
-        return int(tok)
+        with phase("dispatch"):
+            pages, tok = self._chunk_prefill_fn()(
+                self.params, dict(self.cache.pages), ci, cf
+            )
+            self.cache.swap_pages(pages)
+        with phase("sync"):
+            return int(tok)
 
     # ------------------------------------------------------------------
     # legacy whole-prompt prefill (prefill_chunk=None / vlm)
@@ -676,15 +690,17 @@ class ModelExecutor:
                 (1, self.nf, self.cfg.d_model), jnp.dtype(self.cfg.dtype)
             )
         sp = request.sampling
-        pages, tok = self._prefill_fn(bucket)(
-            self.params, batch, jnp.asarray(ctx - 1, jnp.int32),
-            dict(self.cache.pages),
-            self.cache.device_row(slot),
-            jnp.asarray(ctx, jnp.int32),
-            jnp.asarray(sp.temperature, jnp.float32),
-            jnp.asarray(sp.top_k, jnp.int32),
-            jnp.asarray(sp.top_p, jnp.float32),
-            jnp.asarray(seed, jnp.int32),
-        )
-        self.cache.swap_pages(pages)
-        return int(tok)
+        with phase("dispatch"):
+            pages, tok = self._prefill_fn(bucket)(
+                self.params, batch, jnp.asarray(ctx - 1, jnp.int32),
+                dict(self.cache.pages),
+                self.cache.device_row(slot),
+                jnp.asarray(ctx, jnp.int32),
+                jnp.asarray(sp.temperature, jnp.float32),
+                jnp.asarray(sp.top_k, jnp.int32),
+                jnp.asarray(sp.top_p, jnp.float32),
+                jnp.asarray(seed, jnp.int32),
+            )
+            self.cache.swap_pages(pages)
+        with phase("sync"):
+            return int(tok)

@@ -64,7 +64,6 @@ page before its contents exist.
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -72,6 +71,7 @@ import numpy as np
 
 from repro.kernels.ref import dequantize_pages, quantize_kv
 from repro.serving.kv_tiers import KVTierManager, chain_key
+from repro.serving.metrics import phase
 
 NULL_PAGE = 0
 
@@ -310,21 +310,21 @@ class PagedKVCache:
             arrays = tiers.lookup(ck)
             if arrays is None:
                 break
-            t0 = time.perf_counter()
-            (page,) = self.pool.alloc(1)
-            self._upload_page(page, arrays)
-            self.pool.park(page)
-            key = (parent, chunk)
-            self._prefix_index[key] = page
-            self._page_key[page] = key
-            self._page_ck[page] = ck
-            tiers.park(page, ck)
-            tiers.pending.add(page)
-            tiers.counters["prefetched_pages"] += 1
-            tiers.counters["prefetch_bytes"] += sum(
-                a.nbytes for a in arrays.values()
-            )
-            tiers.counters["prefetch_s"] += time.perf_counter() - t0
+            with phase("tier_prefetch") as span:
+                (page,) = self.pool.alloc(1)
+                self._upload_page(page, arrays)
+                self.pool.park(page)
+                key = (parent, chunk)
+                self._prefix_index[key] = page
+                self._page_key[page] = key
+                self._page_ck[page] = ck
+                tiers.park(page, ck)
+                tiers.pending.add(page)
+                tiers.counters["prefetched_pages"] += 1
+                tiers.counters["prefetch_bytes"] += sum(
+                    a.nbytes for a in arrays.values()
+                )
+            tiers.counters["prefetch_s"] += span.ns / 1e9
             parent, parent_ck = page, ck
             i += 1
 
@@ -427,14 +427,14 @@ class PagedKVCache:
                     if key[0] == parent_page:
                         assert child in tiers.parked, (child, key)
                         batch.append((child, tiers.unpark(child)))
-            t0 = time.perf_counter()
-            for page, ck in batch:
-                if tiers.wants_spill:
-                    tiers.spill(ck, self._read_page(page))
-                self._deregister(page)
-                self.pool.reclaim(page)
-                freed += 1
-            tiers.counters["spill_s"] += time.perf_counter() - t0
+            with phase("tier_spill") as span:
+                for page, ck in batch:
+                    if tiers.wants_spill:
+                        tiers.spill(ck, self._read_page(page))
+                    self._deregister(page)
+                    self.pool.reclaim(page)
+                    freed += 1
+            tiers.counters["spill_s"] += span.ns / 1e9
             tiers.counters["reclaimed_pages"] += len(batch)
         return freed
 

@@ -30,11 +30,110 @@ percentile/format logic used by ``launch/serve.py`` and
   metric for speculative decoding: the verify dispatch costs roughly one
   decode step regardless of k, so tokens/step ≈ 1 + accepted/bundle, and
   a rate near zero means speculation is pure overhead for this workload.
+* **Step phases** (:meth:`UtilizationMetrics.step` + :class:`phase`) —
+  each engine step is a ``serving.step`` span split into ``serving.admit``
+  / ``plan`` / ``pack`` / ``dispatch`` / ``sync`` / ``deliver`` phases (and
+  ``tier_prefetch`` / ``tier_spill`` inside them). Every span is a
+  ``jax.profiler.TraceAnnotation``, so it lands in a profiler trace on the
+  device trace's clock, and is timed on ``time.perf_counter_ns`` into a
+  bounded ring of per-step records plus running totals per phase. The
+  split answers where a step's host time goes: admission, StepPlan
+  assembly, input packing, the blocking readback or token delivery.
 """
 
 from __future__ import annotations
 
+import threading
+import time
+from collections import deque
+
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+SPAN_PREFIX = "serving."
+# per-step records kept (the newest); running totals cover every step
+STEP_RING = 65536
+
+# each thread's open spans: filled and emptied by ``with`` blocks, empty
+# between engine steps
+_local = threading.local()
+
+
+def _stack() -> list:
+    """This thread's open spans, outermost first (fleet workers are
+    threads, each stepping its own engine)."""
+    try:
+        return _local.stack
+    except AttributeError:
+        _local.stack = []
+        return _local.stack
+
+
+class StepRecord:
+    """One engine step on ``time.perf_counter_ns``: its start and end, and
+    the self time (ns, nested phases subtracted) of each phase in it."""
+
+    __slots__ = ("start_ns", "end_ns", "phases")
+
+    def __init__(self, start_ns: int, end_ns: int, phases: dict[str, int]):
+        self.start_ns = start_ns
+        self.end_ns = end_ns
+        self.phases = phases
+
+
+class phase:
+    """``with phase("pack"):`` — one ``serving.<name>`` span.
+
+    Enters a profiler ``TraceAnnotation`` and times the block on
+    ``perf_counter_ns`` (``.ns`` after exit). Inside an engine step (see
+    :meth:`UtilizationMetrics.step`) on the same thread, the block's self
+    time — its length less that of phases nested in it — is added to the
+    step's record under ``name``; outside one it is only annotated and
+    timed. Not re-entrant: make a new one per block."""
+
+    __slots__ = ("name", "ns", "_ann", "_t0", "_child")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.ns = 0
+
+    def __enter__(self) -> "phase":
+        self._child = 0
+        self._ann = TraceAnnotation(SPAN_PREFIX + self.name)
+        self._ann.__enter__()
+        _stack().append(self)
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        ns = self.ns = time.perf_counter_ns() - self._t0
+        self._ann.__exit__(*exc)
+        stack = _local.stack  # made by __enter__
+        stack.pop()
+        if stack:
+            stack[-1]._child += ns
+            step = stack[0]
+            if step.__class__ is _Step:
+                phases = step.phases
+                phases[self.name] = (phases.get(self.name, 0)
+                                     + ns - self._child)
+
+
+class _Step(phase):
+    """The ``serving.step`` span: opens a :class:`StepRecord` that the
+    phases inside fill, and hands it to its metrics on exit."""
+
+    __slots__ = ("metrics", "phases")
+
+    def __init__(self, metrics: "UtilizationMetrics"):
+        super().__init__("step")
+        self.metrics = metrics
+        self.phases: dict[str, int] = {}
+
+    def __exit__(self, *exc) -> None:
+        end = time.perf_counter_ns()
+        super().__exit__(*exc)
+        self.metrics._add_step(StepRecord(self._t0, end, self.phases))
 
 
 class UtilizationMetrics:
@@ -68,6 +167,25 @@ class UtilizationMetrics:
         self.spec_proposed = 0
         self.spec_accepted = 0
         self.spec_rollbacks = 0
+        # step phases: the newest per-step records, and ns per phase (self
+        # time; "step" is the whole step) summed over every step
+        self.step_records: deque[StepRecord] = deque(maxlen=STEP_RING)
+        self.phase_ns: dict[str, int] = {}
+        self.phase_steps = 0
+
+    def step(self) -> phase:
+        """``with metrics.step():`` around one engine ``step()``: the
+        ``serving.step`` span, whose record the :class:`phase` blocks run
+        inside it (engine and executor alike) fill."""
+        return _Step(self)
+
+    def _add_step(self, rec: StepRecord) -> None:
+        self.step_records.append(rec)
+        self.phase_steps += 1
+        tot = self.phase_ns
+        tot["step"] = tot.get("step", 0) + rec.end_ns - rec.start_ns
+        for name, ns in rec.phases.items():
+            tot[name] = tot.get(name, 0) + ns
 
     def record(self, *, active: int, slots: int,
                pages_used: int | None = None,
@@ -137,6 +255,27 @@ class UtilizationMetrics:
         self.spec_proposed += other.spec_proposed
         self.spec_accepted += other.spec_accepted
         self.spec_rollbacks += other.spec_rollbacks
+        self.step_records.extend(other.step_records)
+        self.phase_steps += other.phase_steps
+        for name, ns in other.phase_ns.items():
+            self.phase_ns[name] = self.phase_ns.get(name, 0) + ns
+
+    def phase_ms_per_step(self) -> dict[str, float] | None:
+        """Mean ms per engine step of the whole step and of each phase's
+        self time, or None before the first step."""
+        if not self.phase_steps:
+            return None
+        return {name: ns / 1e6 / self.phase_steps
+                for name, ns in self.phase_ns.items()}
+
+    def format_phases(self) -> str:
+        p = self.phase_ms_per_step()
+        if p is None:
+            return "no_step_data"
+        rest = sorted(k for k in p if k != "step")
+        return ";".join([f"step={p['step']:.3f}ms"]
+                        + [f"{k}={p[k]:.3f}" for k in rest]
+                        + [f"steps={self.phase_steps}"])
 
     @property
     def steps(self) -> int:
@@ -299,6 +438,29 @@ def latency_percentiles(results) -> dict | None:
         "itl_ms": tuple(float(x) for x in pi),
         "itl_ms_max": float(max(itls) * 1e3),
     }
+
+
+def wait_percentiles(results) -> dict | None:
+    """p50/p95 (ms) of the queue wait (submitted -> first slot) and the
+    prefill wait (first slot -> first prefill chunk dispatched) over the
+    results that carry them, or None when none does."""
+    out = {}
+    for key in ("queue_wait", "prefill_wait"):
+        vals = [getattr(r, key) for r in results
+                if getattr(r, key, None) is not None]
+        if vals:
+            out[key + "_ms"] = tuple(
+                float(x) for x in np.percentile(vals, [50, 95]) * 1e3)
+    return out or None
+
+
+def format_waits(results) -> str:
+    """``queue_wait_ms_p50=../p95=..;prefill_wait_ms_p50=../p95=..``."""
+    p = wait_percentiles(results)
+    if p is None:
+        return "no_wait_data"
+    return ";".join(f"{k}_p50={v[0]:.1f}/p95={v[1]:.1f}"
+                    for k, v in p.items())
 
 
 def format_latency(results) -> str:

@@ -59,7 +59,7 @@ from repro.serving.executor import (
     validate_serving_mesh,
 )
 from repro.serving.kv_cache import NULL_PAGE, PagedKVCache, cdiv
-from repro.serving.metrics import UtilizationMetrics
+from repro.serving.metrics import UtilizationMetrics, phase
 from repro.serving.scheduler import DecodeInputs, Sequence
 
 __all__ = ["SSMEngine", "SlotStateBank"]
@@ -123,9 +123,9 @@ class SlotStateBank:
 class SSMExecutor:
     """Compute half of the SSM engine: jitted fused decode+sample and
     chunked-prefill step functions under ``shard_map``, plus the packed
-    device mirrors of the decode batch (same ``di``/``df`` packing and
-    steady-state zero-transfer loop as
-    :class:`~repro.serving.executor.ModelExecutor`)."""
+    device mirrors of the decode batch (same ``di``/``df`` packing,
+    steady-state zero-transfer loop and ``pack``/``dispatch``/``sync``
+    phases as :class:`~repro.serving.executor.ModelExecutor`)."""
 
     # di (S, MP+6) int32: block-table row (MP=0 for pure SSM), then
     # [lens, active, tokens, top_ks, seeds, idx]; df (S, 2) f32:
@@ -265,21 +265,21 @@ class SSMExecutor:
     def refresh(self, inputs: DecodeInputs) -> None:
         """Mirror a freshly assembled decode batch to the device (two
         transfers: packed int32 + packed f32)."""
-        self._greedy_only = inputs.greedy_only
-        bt = inputs.block_tables
-        s, mp = bt.shape
-        di = np.empty((s, mp + self._DI_COLS), np.int32)
-        di[:, :mp] = bt
-        di[:, mp] = inputs.lengths
-        di[:, mp + 1] = inputs.active
-        di[:, mp + 2] = inputs.tokens[:, 0]
-        di[:, mp + 3] = inputs.top_ks
-        di[:, mp + 4] = inputs.seeds
-        di[:, mp + 5] = inputs.idx
-        self._di = jnp.asarray(di)
-        self._df = jnp.asarray(
-            np.stack([inputs.temps, inputs.top_ps], axis=1).astype(np.float32)
-        )
+        with phase("pack"):
+            self._greedy_only = inputs.greedy_only
+            bt = inputs.block_tables
+            s, mp = bt.shape
+            di = np.empty((s, mp + self._DI_COLS), np.int32)
+            di[:, :mp] = bt
+            di[:, mp] = inputs.lengths
+            di[:, mp + 1] = inputs.active
+            di[:, mp + 2] = inputs.tokens[:, 0]
+            di[:, mp + 3] = inputs.top_ks
+            di[:, mp + 4] = inputs.seeds
+            di[:, mp + 5] = inputs.idx
+            self._di = jnp.asarray(di)
+            self._df = jnp.asarray(np.stack(
+                [inputs.temps, inputs.top_ps], axis=1).astype(np.float32))
 
     def decode(self, inputs: DecodeInputs | None = None) -> np.ndarray:
         """Run one decode step; ``None`` reuses the device-advanced batch
@@ -287,19 +287,21 @@ class SSMExecutor:
         Returns the sampled token per slot, (S,) int32 on the host."""
         if inputs is not None:
             self.refresh(inputs)
-        fn = self._decode_fn(self._greedy_only)
-        if self.cache is None:
-            state, self._di, toks = fn(
-                self.params, self.bank.state, self._di, self._df
-            )
-        else:
-            pages = dict(self.cache.pages)
-            pages, state, self._di, toks = fn(
-                self.params, pages, self.bank.state, self._di, self._df
-            )
-            self.cache.swap_pages(pages)
-        self.bank.state = state
-        return np.asarray(toks)
+        with phase("dispatch"):
+            fn = self._decode_fn(self._greedy_only)
+            if self.cache is None:
+                state, self._di, toks = fn(
+                    self.params, self.bank.state, self._di, self._df
+                )
+            else:
+                pages = dict(self.cache.pages)
+                pages, state, self._di, toks = fn(
+                    self.params, pages, self.bank.state, self._di, self._df
+                )
+                self.cache.swap_pages(pages)
+            self.bank.state = state
+        with phase("sync"):
+            return np.asarray(toks)
 
     # ------------------------------------------------------------------
     # chunked prefill
@@ -399,29 +401,32 @@ class SSMExecutor:
         token (the request's first token on the prompt's final chunk)."""
         sp = seq.request.sampling
         c = tokens.shape[0]
-        if self.cache is not None:
-            row = self.cache.block_tables[slot]
-            m = row.shape[0]
-            ci = np.empty(m + c + 5, np.int32)
-            ci[:m] = row
-            ci[m:m + c] = tokens
-            ci[m + c:] = (slot, start, valid, sp.top_k, seq.handle.seed)
-        else:
-            ci = np.empty(c + 5, np.int32)
-            ci[:c] = tokens
-            ci[c:] = (slot, start, valid, sp.top_k, seq.handle.seed)
-        cf = np.array([sp.temperature, sp.top_p], np.float32)
-        fn = self._chunk_fn(sp.temperature <= 0)
-        if self.cache is None:
-            state, tok = fn(self.params, self.bank.state,
-                            jnp.asarray(ci), jnp.asarray(cf))
-        else:
-            pages = dict(self.cache.pages)
-            pages, state, tok = fn(self.params, pages, self.bank.state,
-                                   jnp.asarray(ci), jnp.asarray(cf))
-            self.cache.swap_pages(pages)
-        self.bank.state = state
-        return int(tok)
+        with phase("pack"):
+            if self.cache is not None:
+                row = self.cache.block_tables[slot]
+                m = row.shape[0]
+                ci = np.empty(m + c + 5, np.int32)
+                ci[:m] = row
+                ci[m:m + c] = tokens
+                ci[m + c:] = (slot, start, valid, sp.top_k, seq.handle.seed)
+            else:
+                ci = np.empty(c + 5, np.int32)
+                ci[:c] = tokens
+                ci[c:] = (slot, start, valid, sp.top_k, seq.handle.seed)
+            cf = np.array([sp.temperature, sp.top_p], np.float32)
+            ci, cf = jnp.asarray(ci), jnp.asarray(cf)
+        with phase("dispatch"):
+            fn = self._chunk_fn(sp.temperature <= 0)
+            if self.cache is None:
+                state, tok = fn(self.params, self.bank.state, ci, cf)
+            else:
+                pages = dict(self.cache.pages)
+                pages, state, tok = fn(self.params, pages, self.bank.state,
+                                       ci, cf)
+                self.cache.swap_pages(pages)
+            self.bank.state = state
+        with phase("sync"):
+            return int(tok)
 
 
 class SSMEngine(EngineBase):
@@ -553,44 +558,47 @@ class SSMEngine(EngineBase):
         return seq
 
     def _admit(self) -> int:
-        now = time.perf_counter()
-        self._expire_queue(now)
-        admitted = 0
-        while True:
-            req = self.admission.peek(now)
-            if req is None:
-                break
-            if self.hybrid:
-                if not self.cache.can_admit(len(req.prompt)):
+        with phase("admit"):
+            now = time.perf_counter()
+            self._expire_queue(now)
+            admitted = 0
+            while True:
+                req = self.admission.peek(now)
+                if req is None:
                     break
-                slot, _ = self.cache.admit(len(req.prompt))
-            else:
-                if not self._free:
-                    break
-                slot = self._free.pop()
-            self.admission.pop(now)
-            handle = self._handles[req.uid]
-            self._order += 1
-            seq = Sequence(req, handle, [], order=self._order,
-                           phase="prefill", prefill_pos=0)
-            self.slots[slot] = seq
-            admitted += 1
-            parked = self._snapshots.pop(req.uid, None)
-            if parked is not None:
-                # snapshot-preempted: resume decoding where it left off —
-                # the bank gets the parked state verbatim alongside the
-                # attempt's own token list (NOT the handle's delivered
-                # stream, which is longer when the attempt was itself a
-                # regeneration after an earlier discard preemption); its
-                # last entry is the sampled-but-not-yet-fed pending token
-                snap, attempt_tokens = parked
-                self.bank.restore(slot, snap)
-                seq.tokens = list(attempt_tokens)
-                seq.phase = "decode"
-                seq.prefill_pos = len(req.prompt)
-                self._dirty = True
-                self.stats["restores"] += 1
-        return admitted
+                if self.hybrid:
+                    if not self.cache.can_admit(len(req.prompt)):
+                        break
+                    slot, _ = self.cache.admit(len(req.prompt))
+                else:
+                    if not self._free:
+                        break
+                    slot = self._free.pop()
+                self.admission.pop(now)
+                handle = self._handles[req.uid]
+                self._stamp(handle, "admitted")
+                self._order += 1
+                seq = Sequence(req, handle, [], order=self._order,
+                               phase="prefill", prefill_pos=0)
+                self.slots[slot] = seq
+                admitted += 1
+                parked = self._snapshots.pop(req.uid, None)
+                if parked is not None:
+                    # snapshot-preempted: resume decoding where it left
+                    # off — the bank gets the parked state verbatim
+                    # alongside the attempt's own token list (NOT the
+                    # handle's delivered stream, which is longer when the
+                    # attempt was itself a regeneration after an earlier
+                    # discard preemption); its last entry is the
+                    # sampled-but-not-yet-fed pending token
+                    snap, attempt_tokens = parked
+                    self.bank.restore(slot, snap)
+                    seq.tokens = list(attempt_tokens)
+                    seq.phase = "decode"
+                    seq.prefill_pos = len(req.prompt)
+                    self._dirty = True
+                    self.stats["restores"] += 1
+            return admitted
 
     def _first_token(self, slot: int, seq: Sequence, tok: int) -> None:
         """Prompt fully scanned into the slot state: deliver the sampled
@@ -686,36 +694,41 @@ class SSMEngine(EngineBase):
         by one chunk, then run one fused decode dispatch over every
         decoding slot. Cold start (nothing decodable yet) drains prefill
         chunks back-to-back so the first token is never gated on an empty
-        decode batch."""
-        self._admit()
-        while not self._has_decodable():
-            if not self._prefill_step():
-                return self._drain_events()
+        decode batch. The step is a ``serving.step`` span split into
+        phases (``serving/metrics.py``)."""
+        with self.utilization.step():
             self._admit()
-        self._prefill_step()
-        self._decode_once()
-        return self._drain_events()
+            while not self._has_decodable():
+                if not self._prefill_step():
+                    return self._drain_events()
+                self._admit()
+            self._prefill_step()
+            self._decode_once()
+            return self._drain_events()
 
     def _prefill_step(self) -> bool:
-        cand = [(q.order, s) for s, q in self.slots.items()
-                if q.phase == "prefill"]
-        if not cand:
-            return False
-        _, slot = min(cand)
-        seq = self.slots[slot]
-        prompt = seq.request.prompt
-        c = self.prefill_chunk
-        start = seq.prefill_pos
-        valid = min(c, len(prompt) - start)
-        tokens = np.zeros(c, np.int32)
-        tokens[:valid] = prompt[start:start + valid]
+        with phase("plan"):
+            cand = [(q.order, s) for s, q in self.slots.items()
+                    if q.phase == "prefill"]
+            if not cand:
+                return False
+            _, slot = min(cand)
+            seq = self.slots[slot]
+            prompt = seq.request.prompt
+            c = self.prefill_chunk
+            start = seq.prefill_pos
+            valid = min(c, len(prompt) - start)
+            tokens = np.zeros(c, np.int32)
+            tokens[:valid] = prompt[start:start + valid]
+        self._stamp(seq.handle, "prefill_start")
         tok = self.executor.prefill_chunk(slot, seq, tokens, start, valid)
-        self.stats["prefill_chunks"] += 1
-        self.utilization.record_batch(decode_rows=0, prefill_rows=valid,
-                                      padded_rows=c - valid, fused=False)
-        seq.prefill_pos += valid
-        if seq.prefill_pos >= len(prompt):
-            self._first_token(slot, seq, tok)
+        with phase("deliver"):
+            self.stats["prefill_chunks"] += 1
+            self.utilization.record_batch(decode_rows=0, prefill_rows=valid,
+                                          padded_rows=c - valid, fused=False)
+            seq.prefill_pos += valid
+            if seq.prefill_pos >= len(prompt):
+                self._first_token(slot, seq, tok)
         return True
 
     def _decode_inputs(self) -> DecodeInputs:
@@ -754,33 +767,38 @@ class SSMEngine(EngineBase):
         )
 
     def _decode_once(self) -> None:
-        if self.hybrid:
-            self._ensure_decode_pages()
-        decoding = sorted(s for s, q in self.slots.items()
-                          if q.phase == "decode")
-        if not decoding:
-            return
-        if self._dirty:
-            self.executor.refresh(self._decode_inputs())
-            self._dirty = False
-        toks = self.executor.decode()
-        self.stats["decode_steps"] += 1
-        self.utilization.record(
-            active=len(decoding), slots=self.max_slots,
-            pages_used=(self.cache.num_pages - 1 - self.cache.pool.available
-                        if self.hybrid else None),
-            pages_total=self.cache.num_pages - 1 if self.hybrid else None,
-        )
-        self.utilization.record_batch(
-            decode_rows=len(decoding), prefill_rows=0,
-            padded_rows=self.max_slots - len(decoding), fused=False,
-        )
-        now = time.perf_counter()
-        for slot in decoding:
-            seq = self.slots[slot]
-            tok = int(toks[slot])
-            seq.tokens.append(tok)
+        with phase("plan"):
             if self.hybrid:
-                self.cache.append(slot)
-            if self._deliver(seq.handle, tok, len(seq.tokens) - 1, now):
-                self._release(slot)
+                self._ensure_decode_pages()
+            decoding = sorted(s for s, q in self.slots.items()
+                              if q.phase == "decode")
+            if not decoding:
+                return
+            inputs = self._decode_inputs() if self._dirty else None
+            self._dirty = False
+        if inputs is not None:
+            self.executor.refresh(inputs)
+        toks = self.executor.decode()
+        with phase("deliver"):
+            self.stats["decode_steps"] += 1
+            self.utilization.record(
+                active=len(decoding), slots=self.max_slots,
+                pages_used=(self.cache.num_pages - 1
+                            - self.cache.pool.available
+                            if self.hybrid else None),
+                pages_total=(self.cache.num_pages - 1
+                             if self.hybrid else None),
+            )
+            self.utilization.record_batch(
+                decode_rows=len(decoding), prefill_rows=0,
+                padded_rows=self.max_slots - len(decoding), fused=False,
+            )
+            now = time.perf_counter()
+            for slot in decoding:
+                seq = self.slots[slot]
+                tok = int(toks[slot])
+                seq.tokens.append(tok)
+                if self.hybrid:
+                    self.cache.append(slot)
+                if self._deliver(seq.handle, tok, len(seq.tokens) - 1, now):
+                    self._release(slot)

@@ -6,6 +6,9 @@ raises what the chip's compiler would raise (block shapes that break the
 (8, 128) tiling rule, VMEM over-use, unsupported lowerings). Interpret-mode
 tests cannot see any of that.
 
+Each kernel's custom call must carry the kernel's name in the compiled
+HLO: that instruction name is what a profiler trace of the chip prints.
+
 Widths are the published ones: smollm-360m (KVH 5, G 3, D 64), the
 per-shard slice of llama3-8b at tp=4 (KVH 2, G 4, D 128) and mamba2-1.3b
 (64 SSD heads, P 64, N 128).
@@ -19,6 +22,7 @@ worker owns the library.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,10 +70,13 @@ def one_chip():
     cc.reset_cache()
 
 
-def _compile(sharding, fn, *shapes):
+def _compile(sharding, name, fn, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's custom call is named after it: "%<name>[.n] = ... custom-call("
+    assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call\(", text), name
     return compiled
 
 
@@ -80,7 +87,7 @@ def _pool(kvh, d, dtype=jnp.bfloat16):
 @pytest.mark.parametrize("width", ATTN_WIDTHS, ids=lambda w: w[0])
 def test_paged_decode_compiles(one_chip, width):
     _, kvh, g, d = width
-    _compile(one_chip, paged_attention_bkgd,
+    _compile(one_chip, "paged_decode_attention", paged_attention_bkgd,
              ((SLOTS, kvh, g, d), jnp.bfloat16),
              _pool(kvh, d), _pool(kvh, d),
              ((SLOTS, MAX_PAGES), jnp.int32), ((SLOTS,), jnp.int32))
@@ -89,7 +96,8 @@ def test_paged_decode_compiles(one_chip, width):
 @pytest.mark.parametrize("width", ATTN_WIDTHS, ids=lambda w: w[0])
 def test_paged_prefill_compiles(one_chip, width):
     _, kvh, g, d = width
-    _compile(one_chip, paged_prefill_attention_ckgd,
+    _compile(one_chip, "paged_prefill_attention",
+             paged_prefill_attention_ckgd,
              ((CHUNK, kvh, g, d), jnp.bfloat16),
              _pool(kvh, d), _pool(kvh, d),
              ((MAX_PAGES,), jnp.int32), ((), jnp.int32), ((), jnp.int32))
@@ -99,7 +107,7 @@ def test_paged_prefill_compiles(one_chip, width):
 def test_paged_mixed_compiles(one_chip, width):
     _, kvh, g, d = width
     rows = SLOTS + CHUNK
-    _compile(one_chip, paged_mixed_attention_rkgd,
+    _compile(one_chip, "paged_mixed_attention", paged_mixed_attention_rkgd,
              ((rows, kvh, g, d), jnp.bfloat16),
              _pool(kvh, d), _pool(kvh, d),
              ((rows, MAX_PAGES), jnp.int32), ((rows,), jnp.int32))
@@ -111,7 +119,8 @@ def test_paged_mixed_int8_compiles(one_chip):
     fn = lambda q, k, v, ks, vs, bt, lp: paged_mixed_attention_rkgd(
         q, k, v, bt, lp, k_scale=ks, v_scale=vs)
     scales = ((NUM_PAGES, PAGE, kvh), jnp.float32)
-    _compile(one_chip, fn, ((rows, kvh, g, d), jnp.bfloat16),
+    _compile(one_chip, "paged_mixed_attention", fn,
+             ((rows, kvh, g, d), jnp.bfloat16),
              _pool(kvh, d, jnp.int8), _pool(kvh, d, jnp.int8),
              scales, scales,
              ((rows, MAX_PAGES), jnp.int32), ((rows,), jnp.int32))
@@ -120,13 +129,14 @@ def test_paged_mixed_int8_compiles(one_chip):
 @pytest.mark.parametrize("seq", [CHUNK, 512], ids=["chunk64", "seq512"])
 def test_ssd_scan_compiles(one_chip, seq):
     fn = lambda x, dt, a, b, c: ssd_scan_bhsp(x, dt, a, b, c, chunk=256)
-    _compile(one_chip, fn, ((1, SSD_HEADS, seq, SSD_P), jnp.bfloat16),
+    _compile(one_chip, "ssd_chunk_scan", fn,
+             ((1, SSD_HEADS, seq, SSD_P), jnp.bfloat16),
              ((1, SSD_HEADS, seq), jnp.float32), ((SSD_HEADS,), jnp.float32),
              ((1, seq, SSD_N), jnp.bfloat16), ((1, seq, SSD_N), jnp.bfloat16))
 
 
 def test_ssd_decode_step_compiles(one_chip):
-    _compile(one_chip, ssd_decode_step_bh,
+    _compile(one_chip, "ssd_decode_step", ssd_decode_step_bh,
              ((SLOTS, SSD_HEADS, SSD_P, SSD_N), jnp.float32),
              ((SLOTS, SSD_HEADS, SSD_P), jnp.bfloat16),
              ((SLOTS, SSD_HEADS), jnp.float32), ((SSD_HEADS,), jnp.float32),
@@ -136,6 +146,6 @@ def test_ssd_decode_step_compiles(one_chip):
 @pytest.mark.parametrize("width", ATTN_WIDTHS, ids=lambda w: w[0])
 def test_flash_attention_compiles(one_chip, width):
     _, kvh, g, d = width
-    _compile(one_chip, flash_attention_bhsd,
+    _compile(one_chip, "flash_attention", flash_attention_bhsd,
              ((1, kvh * g, 256, d), jnp.bfloat16),
              ((1, kvh, 256, d), jnp.bfloat16), ((1, kvh, 256, d), jnp.bfloat16))
