@@ -108,15 +108,37 @@ def flash_attention(
     raise ValueError(f"unknown attention impl {impl!r}")
 
 
+def _kv_heads(q, kv_pages) -> int:
+    """The pool's (local) kv head count: its lanes over the (K, V) pair's
+    2*D, checked against q's head count."""
+    h, d = q.shape[-2:]
+    kvh = kv_pages.shape[-1] // (2 * d)
+    assert kvh and h % kvh == 0, (
+        f"q heads ({h}) must be a multiple of kv heads ({kvh}) — a sharded "
+        f"caller must slice both by the same tensor-parallel degree"
+    )
+    return kvh
+
+
+def _layer_view(q, kv_pages, kv_scale, layer):
+    """The XLA lowering's logical view of one layer of the stacked
+    lane-dense pool: (K, V), each (P, page, KVH, D), dequantized when
+    ``kv_scale`` is given (``ref.unpack_kv``)."""
+    _kv_heads(q, kv_pages)
+    return ref.unpack_kv(
+        kv_pages[layer], q.shape[-1],
+        None if kv_scale is None else kv_scale[layer],
+    )
+
+
 def paged_attention(
     q: jax.Array,             # (B, H, D) one query token per sequence
-    k_pages: jax.Array,       # (P, page, KVH, D) shared page pool
-    v_pages: jax.Array,
+    kv_pages: jax.Array,      # (L, P, page, KVH*2*D) stacked lane-dense pool
     block_tables: jax.Array,  # (B, MP) int32
     lengths: jax.Array,       # (B,) int32 valid positions per sequence
+    layer: jax.Array,         # scalar int32: the pool's layer to read
     *,
-    k_scale: jax.Array | None = None,  # (P, page, KVH) int8-page scales
-    v_scale: jax.Array | None = None,
+    kv_scale: jax.Array | None = None,  # (L, P, page, KVH*2) int8 scales
     scale: float | None = None,
     impl: str = "auto",
     interpret: bool = False,
@@ -126,16 +148,20 @@ def paged_attention(
     Idle slots (length 0) return zeros rather than NaN, so a continuous
     batcher can keep dead rows in the decode batch.
 
-    Quantized pools: when ``k_scale``/``v_scale`` are given the pages are
-    int8 with one f32 scale per (page, position, kv head). The Pallas path
-    fuses dequant into the page load; the XLA path dequantizes the pool
-    through :func:`ref.dequantize_pages` and runs the unchanged fp32 oracle,
-    so both lowerings share one ground truth.
+    The pool is the serving cache's own array, stacked over layers and
+    lane-dense (``kernels/paged_attention.py``); ``layer`` picks the layer.
+    The Pallas kernel reads that layer's pages straight from the stacked
+    pool; the XLA path reads the logical view :func:`ref.unpack_kv` gives
+    and runs the unchanged fp32 oracle, so both lowerings share one
+    ground truth. Quantized pools: with ``kv_scale`` the pages are int8
+    with one f32 scale per (page, position, kv head) for K and for V; the
+    Pallas path fuses dequant into the page load, the XLA path
+    dequantizes through ``ref.unpack_kv``.
 
     Shard-local contract (sharded serving): under the executor's
     ``shard_map`` this op receives the PER-SHARD head slice — q carries
-    ``H/tp`` heads, the pools carry ``KVH/tp`` kv heads — while
-    ``block_tables``/``lengths`` are replicated (page ids are
+    ``H/tp`` heads, the pool carries ``KVH/tp`` kv heads' lanes — while
+    ``block_tables``/``lengths``/``layer`` are replicated (page ids are
     shard-invariant). Heads shard in contiguous GQA groups, so the grouped
     reshape below is exactly the local slice's own grouping and every impl
     (Pallas and the XLA refs) works unchanged on the slice; the q/kv head
@@ -143,24 +169,16 @@ def paged_attention(
     """
     impl, interpret = _resolve_pallas_impl(impl, interpret, "paged_attention")
     b, h, d = q.shape
-    kvh = k_pages.shape[2]
-    assert kvh and h % kvh == 0, (
-        f"q heads ({h}) must be a multiple of kv heads ({kvh}) — a sharded "
-        f"caller must slice both by the same tensor-parallel degree"
-    )
     if impl in ("naive", "xla_chunked"):
-        if k_scale is not None:
-            k_pages = ref.dequantize_pages(k_pages, k_scale)
-            v_pages = ref.dequantize_pages(v_pages, v_scale)
+        k, v = _layer_view(q, kv_pages, kv_scale, layer)
         return ref.paged_attention_ref(
-            q, k_pages, v_pages, block_tables, lengths, scale=scale
+            q, k, v, block_tables, lengths, scale=scale
         )
     if impl == "pallas":
-        qg = q.reshape(b, kvh, h // kvh, d)
+        kvh = _kv_heads(q, kv_pages)
         out = paged_attention_bkgd(
-            qg, k_pages, v_pages, block_tables, lengths,
-            k_scale=k_scale, v_scale=v_scale,
-            scale=scale, interpret=interpret,
+            q.reshape(b, kvh, h // kvh, d), kv_pages, block_tables, lengths,
+            layer, kv_scale=kv_scale, scale=scale, interpret=interpret,
         )
         return out.reshape(b, h, d)
     raise ValueError(f"unknown paged attention impl {impl!r}")
@@ -168,14 +186,13 @@ def paged_attention(
 
 def paged_prefill_attention(
     q: jax.Array,            # (C, H, D) one prefill chunk of ONE sequence
-    k_pages: jax.Array,      # (P, page, KVH, D) shared page pool
-    v_pages: jax.Array,
+    kv_pages: jax.Array,     # (L, P, page, KVH*2*D) stacked lane-dense pool
     block_table: jax.Array,  # (MP,) int32 the sequence's block-table row
     start: jax.Array,        # scalar int32: positions already cached
     valid: jax.Array,        # scalar int32: real tokens in this chunk
+    layer: jax.Array,        # scalar int32: the pool's layer to read
     *,
-    k_scale: jax.Array | None = None,  # (P, page, KVH) int8-page scales
-    v_scale: jax.Array | None = None,
+    kv_scale: jax.Array | None = None,  # (L, P, page, KVH*2) int8 scales
     scale: float | None = None,
     impl: str = "auto",
     interpret: bool = False,
@@ -187,33 +204,25 @@ def paged_prefill_attention(
     position ``<= start + i`` through the block table, and padded queries
     (``i >= valid``) return zeros. The Pallas kernel
     (:func:`repro.kernels.paged_attention.paged_prefill_attention_ckgd`)
-    mirrors the decode kernel's shard-local contract — under the serving
-    executor's ``shard_map`` it receives the per-shard head slice with the
-    block table replicated — and ``ref.paged_prefill_attention_ref`` stays
+    mirrors the decode kernel's pool and shard-local contract (see
+    :func:`paged_attention`), and ``ref.paged_prefill_attention_ref`` stays
     the oracle and the CPU path.
     """
     impl, interpret = _resolve_pallas_impl(
         impl, interpret, "paged_prefill_attention"
     )
     c, h, d = q.shape
-    kvh = k_pages.shape[2]
-    assert kvh and h % kvh == 0, (
-        f"q heads ({h}) must be a multiple of kv heads ({kvh}) — a sharded "
-        f"caller must slice both by the same tensor-parallel degree"
-    )
     if impl in ("naive", "xla_chunked"):
-        if k_scale is not None:
-            k_pages = ref.dequantize_pages(k_pages, k_scale)
-            v_pages = ref.dequantize_pages(v_pages, v_scale)
+        k, v = _layer_view(q, kv_pages, kv_scale, layer)
         return ref.paged_prefill_attention_ref(
-            q, k_pages, v_pages, block_table, start, valid, scale=scale
+            q, k, v, block_table, start, valid, scale=scale
         )
     if impl == "pallas":
-        qg = q.reshape(c, kvh, h // kvh, d)
+        kvh = _kv_heads(q, kv_pages)
         out = paged_prefill_attention_ckgd(
-            qg, k_pages, v_pages, block_table, start, valid,
-            k_scale=k_scale, v_scale=v_scale,
-            scale=scale, interpret=interpret,
+            q.reshape(c, kvh, h // kvh, d), kv_pages, block_table, start,
+            valid, layer, kv_scale=kv_scale, scale=scale,
+            interpret=interpret,
         )
         return out.reshape(c, h, d)
     raise ValueError(f"unknown paged prefill impl {impl!r}")
@@ -221,13 +230,12 @@ def paged_prefill_attention(
 
 def paged_mixed_attention(
     q: jax.Array,             # (R, H, D) one query row per batch row
-    k_pages: jax.Array,       # (P, page, KVH, D) shared page pool
-    v_pages: jax.Array,
+    kv_pages: jax.Array,      # (L, P, page, KVH*2*D) stacked lane-dense pool
     block_tables: jax.Array,  # (R, MP) int32, one block-table row per row
     last_pos: jax.Array,      # (R,) int32 last attendable position, -1 = dead
+    layer: jax.Array,         # scalar int32: the pool's layer to read
     *,
-    k_scale: jax.Array | None = None,  # (P, page, KVH) int8-page scales
-    v_scale: jax.Array | None = None,
+    kv_scale: jax.Array | None = None,  # (L, P, page, KVH*2) int8 scales
     scale: float | None = None,
     impl: str = "auto",
     interpret: bool = False,
@@ -242,9 +250,8 @@ def paged_mixed_attention(
     chunk rows past ``valid``) use ``last_pos = -1`` and return exact
     zeros. One engine step therefore needs ONE attention dispatch. The
     Pallas kernel (:func:`repro.kernels.paged_attention.
-    paged_mixed_attention_rkgd`) keeps the other paged kernels' shard-local
-    contract — per-shard head slice under the serving executor's
-    ``shard_map``, tables/positions replicated — and
+    paged_mixed_attention_rkgd`) keeps the other paged kernels' pool and
+    shard-local contract (see :func:`paged_attention`), and
     ``ref.paged_mixed_attention_ref`` is the oracle and the CPU path.
 
     ``num_decode`` is an OPTIONAL static structure hint: when set, the
@@ -263,39 +270,29 @@ def paged_mixed_attention(
         impl, interpret, "paged_mixed_attention"
     )
     r, h, d = q.shape
-    kvh = k_pages.shape[2]
-    assert kvh and h % kvh == 0, (
-        f"q heads ({h}) must be a multiple of kv heads ({kvh}) — a sharded "
-        f"caller must slice both by the same tensor-parallel degree"
-    )
     if impl in ("naive", "xla_chunked"):
-        if k_scale is not None:
-            k_pages = ref.dequantize_pages(k_pages, k_scale)
-            v_pages = ref.dequantize_pages(v_pages, v_scale)
+        k, v = _layer_view(q, kv_pages, kv_scale, layer)
         if num_decode is None or not 0 < num_decode < r:
             return ref.paged_mixed_attention_ref(
-                q, k_pages, v_pages, block_tables, last_pos, scale=scale
+                q, k, v, block_tables, last_pos, scale=scale
             )
         s = num_decode
         dec = ref.paged_attention_ref(
-            q[:s], k_pages, v_pages, block_tables[:s], last_pos[:s] + 1,
-            scale=scale,
+            q[:s], k, v, block_tables[:s], last_pos[:s] + 1, scale=scale,
         )
         # dead chunk rows are a suffix, so the live count and the cursor
         # fall out of last_pos; valid == 0 masks every chunk row to zeros
         valid = jnp.sum(last_pos[s:] >= 0).astype(jnp.int32)
         start = jnp.maximum(last_pos[s], 0)
         chk = ref.paged_prefill_attention_ref(
-            q[s:], k_pages, v_pages, block_tables[s], start, valid,
-            scale=scale,
+            q[s:], k, v, block_tables[s], start, valid, scale=scale,
         )
         return jnp.concatenate([dec, chk], axis=0)
     if impl == "pallas":
-        qg = q.reshape(r, kvh, h // kvh, d)
+        kvh = _kv_heads(q, kv_pages)
         out = paged_mixed_attention_rkgd(
-            qg, k_pages, v_pages, block_tables, last_pos,
-            k_scale=k_scale, v_scale=v_scale,
-            scale=scale, interpret=interpret,
+            q.reshape(r, kvh, h // kvh, d), kv_pages, block_tables, last_pos,
+            layer, kv_scale=kv_scale, scale=scale, interpret=interpret,
         )
         return out.reshape(r, h, d)
     raise ValueError(f"unknown paged mixed impl {impl!r}")

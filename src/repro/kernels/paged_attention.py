@@ -32,14 +32,21 @@ across the pages of one query block, reusing the scratch pattern from
 block may read map to the reserved null page 0 and are skipped via
 ``pl.when``.
 
-Each grid cell loads ONE page for ALL local kv heads — a ``(1, page, KVH,
-D)`` block whose two minor dims equal the pool's, which is what the TPU
+The pool is stacked over layers and lane-dense (``kv_cache.PagedKVCache``):
+``kv_pages`` is ``(L, P, page, KVH*2*D)``, kv head h's K then its V in
+lanes ``[2Dh, 2D(h+1))`` (``ref.pack_kv``). Every kernel takes the WHOLE
+stacked pool plus the layer as a scalar prefetch, and its page BlockSpec
+maps a grid cell to ``(layer, bt[.., p], 0, 0)``: no per-layer slice of the
+pool ever exists outside VMEM, so the model's layer loop can carry the
+pool and write each layer's new rows in place. A page block ``(1, 1,
+page, KVH*2*D)`` has the pool's own two minor dims, which the TPU
 compiler's tiling rule (minor block dims divisible by (8, 128) or equal
-to the array's) admits for any head count and head dim; a per-head
-``(1, page, 1, D)`` block is refused whenever KVH > 1. The kernel then
-walks the heads with a static loop (``k_ref[0, :, h]`` is a strided VMEM
-read, no HBM traffic). GQA is native: q arrives grouped ``(.., KVH, G,
-D)`` and head h's G grouped queries attend to kv head h's page rows.
+to the array's) admits for any head count, and for D = 64 each head's
+K|V pair is one 128-lane group: the block fills (16, 128) bf16 tiles
+with no padding. The kernel walks the heads with a static loop; head h's
+K and V are static lane slices of the VMEM block (no HBM traffic). GQA is
+native: q arrives grouped ``(.., KVH, G, D)`` and head h's G grouped
+queries attend to kv head h's page rows.
 
 * decode / mixed grid: (row, logical-page); q block ``(1, KVH, G, D)``.
 * prefill grid: (logical-page,); the chunk's grouped queries (flattened to
@@ -49,20 +56,22 @@ D)`` and head h's G grouped queries attend to kv head h's page rows.
   history length 0 all fall out of the one mask. Pages wholly past the
   chunk's last live query (``p*page >= start+valid``) are skipped.
 
-All three kernels optionally take int8 pages with per-(position, head)
-``k_scale``/``v_scale`` pools (shape (P, page, KVH), f32): the scales ride
-the SAME scalar-prefetched block table as their pages (one ``(1, page,
-KVH)`` block per cell) and dequantization is fused into the VMEM page load
-(``k * scale[:, h]``), so no HBM-resident f32 copy ever exists. Oracle:
-``ref.dequantize_pages`` + the fp32 refs.
+All three kernels optionally take int8 pages with a per-(position, head)
+``kv_scale`` pool (shape (L, P, page, KVH*2), f32, head h's K scale then
+its V scale, ``ref.pack_kv_scales``): the scales ride the SAME
+scalar-prefetched layer and block table as their pages (one ``(1, 1, page,
+KVH*2)`` block per cell) and dequantization is fused into the VMEM page
+load (``k * scale[:, 2h]``), so no HBM-resident f32 copy ever exists.
+Oracle: ``ref.unpack_kv`` (which dequantizes) + the fp32 refs.
 
 Tensor-parallel serving dispatches every kernel PER SHARD: the serving
-executor's ``shard_map`` hands each device its contiguous kv-head slice of
-the page pool (KVH/tp heads) and the matching grouped-q slice, with block
-tables and lengths replicated. Nothing in the kernels changes — the page
-block's head extent is just the local ``KVH/tp``, and because pages shard
-only along the head dim, the scalar-prefetched block-table values
-(physical page ids) are identical on every shard.
+executor's ``shard_map`` hands each device its contiguous lane range of
+the page pool (KVH/tp whole head groups) and the matching grouped-q slice,
+with block tables, lengths and the layer replicated. Nothing in the
+kernels changes — the page block's lane extent is just the local
+``KVH/tp*2*D``, and because pages shard only along the lanes, the
+scalar-prefetched block-table values (physical page ids) are identical on
+every shard.
 """
 
 from __future__ import annotations
@@ -78,20 +87,20 @@ NEG_INF = -1e30
 _LANES = 128  # TPU vector lane count; scratch stats padded to it
 
 
-def _page_walk(q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-               acc_ref, m_ref, l_ref, *, p, run, mask, scale: float,
-               num_logical_pages: int):
+def _page_walk(q_ref, kv_ref, s_ref, o_ref, acc_ref, m_ref, l_ref, *, p,
+               run, mask, scale: float, num_logical_pages: int):
     """Online-softmax step of one page for every local kv head.
 
-    ``q_ref``/``o_ref``: ``(1, KVH, rows, D)`` blocks; ``k_ref``/``v_ref``:
-    ``(1, page, KVH, D)``; ``ks_ref``/``vs_ref``: ``(1, page, KVH)`` int8
-    scales or None; scratch ``acc (KVH, rows, D)``, ``m``/``l (KVH, rows,
-    LANES)`` (column 0 used). ``p`` is the logical page, ``run`` whether
-    this page holds any position the block may read, and ``mask(shape)``
-    the (rows, page) bool of attendable (query, key) pairs — the same for
-    every head. Rows that attend nothing finalize to exact zeros.
+    ``q_ref``/``o_ref``: ``(1, KVH, rows, D)`` blocks; ``kv_ref``: ``(1, 1,
+    page, KVH*2*D)``, head h's K in lanes ``[2Dh, 2Dh+D)`` and its V in
+    ``[2Dh+D, 2D(h+1))``; ``s_ref``: ``(1, 1, page, KVH*2)`` int8 scales or
+    None; scratch ``acc (KVH, rows, D)``, ``m``/``l (KVH, rows, LANES)``
+    (column 0 used). ``p`` is the logical page, ``run`` whether this page
+    holds any position the block may read, and ``mask(shape)`` the (rows,
+    page) bool of attendable (query, key) pairs — the same for every head.
+    Rows that attend nothing finalize to exact zeros.
     """
-    kvh = k_ref.shape[2]
+    kvh, d = q_ref.shape[1], q_ref.shape[3]
 
     @pl.when(p == 0)
     def _init():
@@ -104,13 +113,14 @@ def _page_walk(q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
         ok = None
         for h in range(kvh):
             q = q_ref[0, h].astype(jnp.float32)        # (rows, D)
-            k = k_ref[0, :, h].astype(jnp.float32)     # (page, D)
-            v = v_ref[0, :, h].astype(jnp.float32)
-            if ks_ref is not None:
+            lo = 2 * d * h
+            k = kv_ref[0, 0, :, lo:lo + d].astype(jnp.float32)  # (page, D)
+            v = kv_ref[0, 0, :, lo + d:lo + 2 * d].astype(jnp.float32)
+            if s_ref is not None:
                 # int8 pages: dequant fused into the page load — one row
                 # scale per (position, head), never materialized in HBM
-                k = k * ks_ref[0, :, h:h + 1]
-                v = v * vs_ref[0, :, h:h + 1]
+                k = k * s_ref[0, 0, :, 2 * h:2 * h + 1]
+                v = v * s_ref[0, 0, :, 2 * h + 1:2 * h + 2]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -143,33 +153,41 @@ def _page_walk(q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
 
 
 def _split_rest(rest, quant: bool):
-    """[ks_ref, vs_ref when quant], o_ref, acc_ref, m_ref, l_ref."""
+    """[s_ref when quant], o_ref, acc_ref, m_ref, l_ref."""
     if quant:
         return rest
-    return (None, None) + tuple(rest)
+    return (None,) + tuple(rest)
 
 
-def _paged_call(kernel, prefetch, q, k_pages, v_pages, k_scale, v_scale, *,
+def _paged_call(kernel, prefetch, q, kv_pages, kv_scale, layer, *,
                 name: str, grid, q_map, page_map, interpret: bool):
     """Shared ``pallas_call`` plumbing of the three kernels: q/out blocks of
-    ``(1, KVH, rows, D)``, all-head page (and scale) blocks at the physical
-    page ``page_map`` reads from the prefetched table, and the per-head
-    online-softmax scratch. ``name`` names the kernel's custom call in the
-    compiled HLO, and so in a profiler trace."""
+    ``(1, KVH, rows, D)``, one page of one layer of the stacked pool (and
+    its scales) per grid cell, and the per-head online-softmax scratch.
+    The layer is the first scalar prefetch; ``q_map`` and ``page_map``
+    take the grid indices and the kernel's own prefetch refs, and
+    ``page_map`` returns the physical page. ``name`` names the kernel's
+    custom call in the compiled HLO, and so in a profiler trace."""
     _, kvh, rows, d = q.shape
-    page_size = k_pages.shape[1]
-    page_spec = pl.BlockSpec((1, page_size, kvh, d), page_map)
-    q_spec = pl.BlockSpec((1, kvh, rows, d), q_map)
-    in_specs = [q_spec, page_spec, page_spec]
-    operands = [q, k_pages, v_pages]
-    if k_scale is not None:
-        scale_spec = pl.BlockSpec(
-            (1, page_size, kvh), lambda *ix: page_map(*ix)[:3]
+    _, _, page_size, width = kv_pages.shape
+    ng = len(grid)
+
+    def pool_spec(width_):
+        return pl.BlockSpec(
+            (1, 1, page_size, width_),
+            lambda *ix: (ix[ng][0], page_map(*ix[:ng], *ix[ng + 1:]), 0, 0),
         )
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
+
+    q_spec = pl.BlockSpec(
+        (1, kvh, rows, d), lambda *ix: q_map(*ix[:ng], *ix[ng + 1:])
+    )
+    in_specs = [q_spec, pool_spec(width)]
+    operands = [q, kv_pages]
+    if kv_scale is not None:
+        in_specs.append(pool_spec(kv_scale.shape[-1]))
+        operands.append(kv_scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
+        num_scalar_prefetch=1 + len(prefetch),
         grid=grid,
         in_specs=in_specs,
         out_specs=q_spec,
@@ -185,13 +203,16 @@ def _paged_call(kernel, prefetch, q, k_pages, v_pages, k_scale, v_scale, *,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name=name,
-    )(*prefetch, *operands)
+    )(jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), *prefetch,
+      *operands)
 
 
-def _check(q, k_pages, k_scale, v_scale, scale):
+def _check(q, kv_pages, kv_scale, scale):
     kvh, d = q.shape[1], q.shape[-1]
-    assert k_pages.shape[2] == kvh, (k_pages.shape, kvh)
-    assert (k_scale is None) == (v_scale is None), "k_scale/v_scale go together"
+    assert kv_pages.ndim == 4 and kv_pages.shape[-1] == kvh * 2 * d, (
+        kv_pages.shape, kvh, d)
+    assert kv_scale is None or kv_scale.shape == kv_pages.shape[:-1] + (
+        kvh * 2,), (kv_scale.shape, kv_pages.shape)
     return scale if scale is not None else d ** -0.5
 
 
@@ -201,10 +222,11 @@ def _check(q, k_pages, k_scale, v_scale, scale):
 
 
 def _paged_kernel(
+    layer_ref,  # (1,) int32 scalar-prefetch: the pool's layer (index maps)
     bt_ref,    # (B, MP) int32 scalar-prefetch: block tables
     len_ref,   # (B,)  int32 scalar-prefetch: valid positions per sequence
-    q_ref, k_ref, v_ref,  # VMEM blocks
-    *rest,     # [ks_ref, vs_ref when quant], o_ref, acc_ref, m_ref, l_ref
+    q_ref, kv_ref,  # VMEM blocks
+    *rest,     # [s_ref when quant], o_ref, acc_ref, m_ref, l_ref
     scale: float,
     page_size: int,
     num_logical_pages: int,
@@ -219,35 +241,34 @@ def _paged_kernel(
         return pos < length
 
     # pages entirely past the valid prefix hold no live positions: skip
-    _page_walk(q_ref, k_ref, v_ref, *_split_rest(rest, quant),
+    _page_walk(q_ref, kv_ref, *_split_rest(rest, quant),
                p=p, run=p * page_size < length, mask=mask, scale=scale,
                num_logical_pages=num_logical_pages)
 
 
 def paged_attention_bkgd(
     q: jax.Array,             # (B, KVH, G, D) grouped query, one token per seq
-    k_pages: jax.Array,       # (P, page, KVH, D)
-    v_pages: jax.Array,
+    kv_pages: jax.Array,      # (L, P, page, KVH*2*D) stacked lane-dense pool
     block_tables: jax.Array,  # (B, MP) int32
     lengths: jax.Array,       # (B,) int32
+    layer: jax.Array,         # scalar int32: the pool's layer to read
     *,
-    k_scale: jax.Array | None = None,  # (P, page, KVH) f32 int8-page scales
-    v_scale: jax.Array | None = None,
+    kv_scale: jax.Array | None = None,  # (L, P, page, KVH*2) f32 int8 scales
     scale: float | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    scale = _check(q, k_pages, k_scale, v_scale, scale)
+    scale = _check(q, kv_pages, kv_scale, scale)
     b, mp = block_tables.shape
     kernel = functools.partial(
-        _paged_kernel, scale=scale, page_size=k_pages.shape[1],
-        num_logical_pages=mp, quant=k_scale is not None,
+        _paged_kernel, scale=scale, page_size=kv_pages.shape[2],
+        num_logical_pages=mp, quant=kv_scale is not None,
     )
     return _paged_call(
-        kernel, (block_tables, lengths), q, k_pages, v_pages,
-        k_scale, v_scale, name="paged_decode_attention", grid=(b, mp),
+        kernel, (block_tables, lengths), q, kv_pages, kv_scale, layer,
+        name="paged_decode_attention", grid=(b, mp),
         q_map=lambda b_, p_, bt, ln: (b_, 0, 0, 0),
         # physical page comes from the prefetched block table
-        page_map=lambda b_, p_, bt, ln: (bt[b_, p_], 0, 0, 0),
+        page_map=lambda b_, p_, bt, ln: bt[b_, p_],
         interpret=interpret,
     )
 
@@ -258,10 +279,11 @@ def paged_attention_bkgd(
 
 
 def _paged_prefill_kernel(
+    layer_ref,  # (1,) int32 scalar-prefetch: the pool's layer (index maps)
     bt_ref,    # (MP,) int32 scalar-prefetch: the sequence's block-table row
     meta_ref,  # (2,)  int32 scalar-prefetch: [start, valid]
-    q_ref, k_ref, v_ref,  # VMEM blocks
-    *rest,     # [ks_ref, vs_ref when quant], o_ref, acc_ref, m_ref, l_ref
+    q_ref, kv_ref,  # VMEM blocks
+    *rest,     # [s_ref when quant], o_ref, acc_ref, m_ref, l_ref
     scale: float,
     page_size: int,
     num_logical_pages: int,
@@ -282,29 +304,29 @@ def _paged_prefill_kernel(
 
     # pages wholly past the chunk's last live query attend nothing: skip.
     # (valid == 0 leaves every row fully masked -> exact zeros, like the ref)
-    _page_walk(q_ref, k_ref, v_ref, *_split_rest(rest, quant),
+    _page_walk(q_ref, kv_ref, *_split_rest(rest, quant),
                p=p, run=p * page_size < start + valid, mask=mask,
                scale=scale, num_logical_pages=num_logical_pages)
 
 
 def paged_prefill_attention_ckgd(
     q: jax.Array,            # (C, KVH, G, D) grouped chunk queries, ONE seq
-    k_pages: jax.Array,      # (P, page, KVH, D)
-    v_pages: jax.Array,
+    kv_pages: jax.Array,     # (L, P, page, KVH*2*D) stacked lane-dense pool
     block_table: jax.Array,  # (MP,) int32 the sequence's block-table row
     start: jax.Array,        # scalar int32: positions already cached
     valid: jax.Array,        # scalar int32: real (non-padded) chunk tokens
+    layer: jax.Array,        # scalar int32: the pool's layer to read
     *,
-    k_scale: jax.Array | None = None,  # (P, page, KVH) f32 int8-page scales
-    v_scale: jax.Array | None = None,
+    kv_scale: jax.Array | None = None,  # (L, P, page, KVH*2) f32 int8 scales
     scale: float | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Chunked-prefill paged attention; mirrors the decode kernel's contract
-    (scalar-prefetched block table, per-shard head slice under the serving
-    executor's ``shard_map``). Returns (C, KVH, G, D) in q.dtype."""
+    (scalar-prefetched layer and block table, per-shard head slice under
+    the serving executor's ``shard_map``). Returns (C, KVH, G, D) in
+    q.dtype."""
     c, kvh, group, d = q.shape
-    scale = _check(q, k_pages, k_scale, v_scale, scale)
+    scale = _check(q, kv_pages, kv_scale, scale)
     mp = block_table.shape[0]
     # (C, KVH, G, D) -> (1, KVH, C*G, D): all of one kv head's grouped
     # queries become contiguous rows of one matmul operand
@@ -313,14 +335,14 @@ def paged_prefill_attention_ckgd(
         jnp.asarray(start, jnp.int32), jnp.asarray(valid, jnp.int32)
     ])
     kernel = functools.partial(
-        _paged_prefill_kernel, scale=scale, page_size=k_pages.shape[1],
-        num_logical_pages=mp, group=group, quant=k_scale is not None,
+        _paged_prefill_kernel, scale=scale, page_size=kv_pages.shape[2],
+        num_logical_pages=mp, group=group, quant=kv_scale is not None,
     )
     out = _paged_call(
-        kernel, (block_table, meta), qf, k_pages, v_pages,
-        k_scale, v_scale, name="paged_prefill_attention", grid=(mp,),
+        kernel, (block_table, meta), qf, kv_pages, kv_scale, layer,
+        name="paged_prefill_attention", grid=(mp,),
         q_map=lambda p_, bt, mt: (0, 0, 0, 0),
-        page_map=lambda p_, bt, mt: (bt[p_], 0, 0, 0),
+        page_map=lambda p_, bt, mt: bt[p_],
         interpret=interpret,
     )
     return jnp.transpose(out.reshape(kvh, c, group, d), (1, 0, 2, 3))
@@ -332,10 +354,11 @@ def paged_prefill_attention_ckgd(
 
 
 def _paged_mixed_kernel(
+    layer_ref,  # (1,) int32 scalar-prefetch: the pool's layer (index maps)
     bt_ref,    # (R, MP) int32 scalar-prefetch: block-table row per query row
     lp_ref,    # (R,)   int32 scalar-prefetch: last attendable position, -1 dead
-    q_ref, k_ref, v_ref,  # VMEM blocks
-    *rest,     # [ks_ref, vs_ref when quant], o_ref, acc_ref, m_ref, l_ref
+    q_ref, kv_ref,  # VMEM blocks
+    *rest,     # [s_ref when quant], o_ref, acc_ref, m_ref, l_ref
     scale: float,
     page_size: int,
     num_logical_pages: int,
@@ -353,39 +376,38 @@ def _paged_mixed_kernel(
 
     # pages entirely past the row's last attendable position hold nothing
     # it may read: skip. A dead row (lp < 0) skips every page -> exact zeros.
-    _page_walk(q_ref, k_ref, v_ref, *_split_rest(rest, quant),
+    _page_walk(q_ref, kv_ref, *_split_rest(rest, quant),
                p=p, run=p * page_size <= lp, mask=mask, scale=scale,
                num_logical_pages=num_logical_pages)
 
 
 def paged_mixed_attention_rkgd(
     q: jax.Array,             # (R, KVH, G, D) grouped query, one row per row
-    k_pages: jax.Array,       # (P, page, KVH, D)
-    v_pages: jax.Array,
+    kv_pages: jax.Array,      # (L, P, page, KVH*2*D) stacked lane-dense pool
     block_tables: jax.Array,  # (R, MP) int32, one block-table row per row
     last_pos: jax.Array,      # (R,) int32 last attendable position, -1 = dead
+    layer: jax.Array,         # scalar int32: the pool's layer to read
     *,
-    k_scale: jax.Array | None = None,  # (P, page, KVH) f32 int8-page scales
-    v_scale: jax.Array | None = None,
+    kv_scale: jax.Array | None = None,  # (L, P, page, KVH*2) f32 int8 scales
     scale: float | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Fused mixed-step paged attention; the decode kernel's grid with the
     prefill kernel's per-row causal predicate collapsed to one prefetched
     scalar per row. Same shard-local contract as the other two kernels
-    (per-shard head slice under the executor's ``shard_map``, tables and
-    positions replicated). Returns (R, KVH, G, D) in q.dtype."""
-    scale = _check(q, k_pages, k_scale, v_scale, scale)
+    (per-shard head slice under the executor's ``shard_map``, tables,
+    positions and layer replicated). Returns (R, KVH, G, D) in q.dtype."""
+    scale = _check(q, kv_pages, kv_scale, scale)
     r, mp = block_tables.shape
     kernel = functools.partial(
-        _paged_mixed_kernel, scale=scale, page_size=k_pages.shape[1],
-        num_logical_pages=mp, quant=k_scale is not None,
+        _paged_mixed_kernel, scale=scale, page_size=kv_pages.shape[2],
+        num_logical_pages=mp, quant=kv_scale is not None,
     )
     return _paged_call(
-        kernel, (block_tables, last_pos), q, k_pages, v_pages,
-        k_scale, v_scale, name="paged_mixed_attention", grid=(r, mp),
+        kernel, (block_tables, last_pos), q, kv_pages, kv_scale, layer,
+        name="paged_mixed_attention", grid=(r, mp),
         q_map=lambda r_, p_, bt, lp: (r_, 0, 0, 0),
         # physical page comes from the row's prefetched block table
-        page_map=lambda r_, p_, bt, lp: (bt[r_, p_], 0, 0, 0),
+        page_map=lambda r_, p_, bt, lp: bt[r_, p_],
         interpret=interpret,
     )

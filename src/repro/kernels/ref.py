@@ -26,6 +26,10 @@ models use on non-TPU backends.
   recurrence.
 * ``ssd_chunked``                 — the SSD block-decomposition (Dao & Gu
   2024), matches ``ssd_sequential``; what the models lower on XLA.
+
+The paged oracles read the logical ``(P, page, KVH, D)`` view of one
+layer's K and V; the serving pool stores them lane-dense and stacked over
+layers, and ``unpack_kv`` is the one way from that layout to this view.
 """
 
 from __future__ import annotations
@@ -163,6 +167,40 @@ def dequantize_pages(pages: jax.Array, scales: jax.Array) -> jax.Array:
     ``dequantize_pages`` + the existing oracle in the fuzz harness.
     """
     return pages.astype(jnp.float32) * scales[..., None]
+
+
+# ---------------------------------------------------------------------------
+# the page pool's lane-dense layout
+# ---------------------------------------------------------------------------
+
+
+def pack_kv(k: jax.Array, v: jax.Array) -> jax.Array:
+    """Logical K and V (..., KVH, D) -> the page pool's lane-dense rows
+    (..., KVH*2*D): kv head h's K then its V fill lanes [2Dh, 2D(h+1)), so
+    a page's minor dims fill whole (16, 128) bf16 tiles and a kv-head shard
+    is one contiguous lane range."""
+    return jnp.concatenate([k, v], axis=-1).reshape(*k.shape[:-2], -1)
+
+
+def pack_kv_scales(k_scale: jax.Array, v_scale: jax.Array) -> jax.Array:
+    """int8 scales (..., KVH) each -> (..., KVH*2): head h's K scale, then
+    its V scale, in the same head order as :func:`pack_kv`."""
+    return jnp.stack([k_scale, v_scale], axis=-1).reshape(
+        *k_scale.shape[:-1], -1)
+
+
+def unpack_kv(kv: jax.Array, head_dim: int,
+              kv_scale: jax.Array | None = None) -> tuple[jax.Array, jax.Array]:
+    """The inverse of :func:`pack_kv`: lane-dense rows (..., KVH*2*D) ->
+    logical (K, V), each (..., KVH, D). With ``kv_scale`` (int8 pools) both
+    come back dequantized to f32. The one view through which the oracles,
+    the XLA lowering and the tests read the pool."""
+    x = kv.reshape(*kv.shape[:-1], -1, 2, head_dim)
+    k, v = x[..., 0, :], x[..., 1, :]
+    if kv_scale is not None:
+        s = kv_scale.reshape(*kv_scale.shape[:-1], -1, 2)
+        k, v = dequantize_pages(k, s[..., 0]), dequantize_pages(v, s[..., 1])
+    return k, v
 
 
 # ---------------------------------------------------------------------------

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
-from repro.kernels.ref import quantize_kv
+from repro.kernels.ref import pack_kv, pack_kv_scales, quantize_kv
 from repro.models import common
 from repro.models.common import ParamSpec, apply_rope, rms_norm, rope_table
 from repro.parallel import constrain
@@ -146,34 +146,36 @@ def decode_self_attention(
 
 
 def _paged_scatter(
-    layer_pages: dict, k_rows: jax.Array, v_rows: jax.Array,
+    pages: dict, layer: jax.Array, k_rows: jax.Array, v_rows: jax.Array,
     phys: jax.Array, off: jax.Array,
 ) -> dict:
-    """Scatter per-row K/V into this layer's page pool (indices (rows,)).
+    """Write per-row K/V (rows, KVH, Dh) into the stacked lane-dense pool
+    at ``(layer, phys, off)`` (indices (rows,)), packed as ``ref.pack_kv``
+    lays them out. Inside the model's layer loop the pool is the loop's
+    carry, so this scatter updates it in place: the only pool-shaped op a
+    paged step runs.
 
-    int8 pools (``k_scale`` present) quantize on the way in — one f32 scale
-    per (row, kv head) over head_dim — and scatter the scales alongside, so
-    the paged kernels can fuse the dequant. Out-of-bounds rows are dropped
-    for values and scales alike."""
-    out = dict(layer_pages)
-    if "k_scale" in layer_pages:
+    int8 pools (``kv_scale`` present) quantize on the way in — one f32 scale
+    per (row, kv head) over head_dim, for K and for V — and scatter the
+    scales alongside, so the paged kernels can fuse the dequant.
+    Out-of-bounds rows are dropped for values and scales alike."""
+    out = dict(pages)
+    if "kv_scale" in pages:
         k_rows, k_sc = quantize_kv(k_rows)
         v_rows, v_sc = quantize_kv(v_rows)
-        out["k_scale"] = layer_pages["k_scale"].at[phys, off].set(
-            k_sc, mode="drop")
-        out["v_scale"] = layer_pages["v_scale"].at[phys, off].set(
-            v_sc, mode="drop")
-    out["k"] = layer_pages["k"].at[phys, off].set(
-        k_rows.astype(layer_pages["k"].dtype), mode="drop")
-    out["v"] = layer_pages["v"].at[phys, off].set(
-        v_rows.astype(layer_pages["v"].dtype), mode="drop")
+        out["kv_scale"] = pages["kv_scale"].at[layer, phys, off].set(
+            pack_kv_scales(k_sc, v_sc), mode="drop")
+    dtype = pages["kv"].dtype
+    out["kv"] = pages["kv"].at[layer, phys, off].set(
+        pack_kv(k_rows.astype(dtype), v_rows.astype(dtype)), mode="drop")
     return out
 
 
 def decode_self_attention_paged(
     p: dict,
     x: jax.Array,            # (S, 1, D) one token per in-flight slot
-    layer_pages: dict,       # {"k": (P,page,KVH,Dh), "v": ...} this layer's pool
+    pages: dict,             # {"kv": (L,P,page,KVH*2*Dh)} the stacked pool
+    layer: jax.Array,        # scalar int32: this layer's index in the pool
     block_tables: jax.Array,  # (S, MP) int32
     lengths: jax.Array,      # (S,) int32 tokens already cached per slot
     cfg: ModelConfig,
@@ -182,11 +184,12 @@ def decode_self_attention_paged(
     attn_impl: str = "xla_chunked",
 ) -> tuple[jax.Array, dict]:
     """Continuous-batching decode: write the new K/V into each slot's current
-    page, then attend over the block table. Per-slot positions (= lengths)
-    drive RoPE, so slots at different depths coexist in one batch."""
+    page of this layer, then attend over the block table. Per-slot
+    positions (= lengths) drive RoPE, so slots at different depths coexist
+    in one batch. Returns (output, the updated pool)."""
     positions = lengths[:, None]  # (S, 1) absolute position of the new token
     q, k, v = _project_qkv(p, x, cfg, positions, rope)
-    num_pages, page = layer_pages["k"].shape[:2]
+    num_pages, page = pages["kv"].shape[1:3]
     phys = jnp.take_along_axis(
         block_tables, (lengths // page)[:, None], axis=1
     )[:, 0]
@@ -195,24 +198,25 @@ def decode_self_attention_paged(
     # update order is well-defined (duplicate-index scatter is not)
     phys = jnp.where(phys == 0, num_pages, phys)
     off = lengths % page
-    cache = _paged_scatter(layer_pages, k[:, 0], v[:, 0], phys, off)
+    pages = _paged_scatter(pages, layer, k[:, 0], v[:, 0], phys, off)
     out = ops.paged_attention(
-        q[:, 0], cache["k"], cache["v"], block_tables, lengths + 1,
-        k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"),
-        scale=cfg.head_dim ** -0.5, impl=attn_impl,
+        q[:, 0], pages["kv"], block_tables, lengths + 1, layer,
+        kv_scale=pages.get("kv_scale"), scale=cfg.head_dim ** -0.5,
+        impl=attn_impl,
     ).astype(x.dtype)  # (S, H_local, Dh)
     # under the serving executor's shard_map, q/kv heads and the page pool
     # are head-sharded: each shard attends its own head slice against its
     # own KV shard (block tables are replicated), and the row-parallel wo
     # partial sums are reduced here
     o = psum_tp(jnp.einsum("bhk,hkd->bd", out, p["wo"]))[:, None, :]
-    return o, cache
+    return o, pages
 
 
 def prefill_chunk_attention_paged(
     p: dict,
     x: jax.Array,            # (1, C, D) one chunk of ONE sequence's prompt
-    layer_pages: dict,       # {"k": (P,page,KVH,Dh), "v": ...} this layer's pool
+    pages: dict,             # {"kv": (L,P,page,KVH*2*Dh)} the stacked pool
+    layer: jax.Array,        # scalar int32: this layer's index in the pool
     block_table: jax.Array,  # (MP,) int32 the sequence's block-table row
     start: jax.Array,        # scalar int32: positions already in the pages
     valid: jax.Array,        # scalar int32: real (non-padded) chunk tokens
@@ -221,12 +225,12 @@ def prefill_chunk_attention_paged(
     rope: bool = True,
     attn_impl: str = "xla_chunked",
 ) -> tuple[jax.Array, dict]:
-    """Chunked prefill: scatter the chunk's K/V into the sequence's pages,
-    then attend each chunk position over the paged prefix + the chunk itself
-    (causal). RoPE uses absolute positions ``start + i``, so a chunk never
-    knows (or re-pads to) the full prompt length. Padded positions
-    (>= valid) write out of bounds (dropped) and return garbage outputs the
-    caller discards.
+    """Chunked prefill: scatter the chunk's K/V into the sequence's pages
+    of this layer, then attend each chunk position over the paged prefix +
+    the chunk itself (causal). RoPE uses absolute positions ``start + i``,
+    so a chunk never knows (or re-pads to) the full prompt length. Padded
+    positions (>= valid) write out of bounds (dropped) and return garbage
+    outputs the caller discards.
 
     ``attn_impl`` selects the attention lowering exactly like decode:
     "pallas"/"auto"-on-TPU dispatches the Pallas chunk-prefill kernel
@@ -236,25 +240,26 @@ def prefill_chunk_attention_paged(
     c = x.shape[1]
     positions = start + jnp.arange(c)
     q, k, v = _project_qkv(p, x, cfg, positions, rope)
-    num_pages, page = layer_pages["k"].shape[:2]
+    num_pages, page = pages["kv"].shape[1:3]
     phys = jnp.where(
         jnp.arange(c) < valid, block_table[positions // page], num_pages
     )
     off = positions % page
-    cache = _paged_scatter(layer_pages, k[0], v[0], phys, off)
+    pages = _paged_scatter(pages, layer, k[0], v[0], phys, off)
     out = ops.paged_prefill_attention(
-        q[0], cache["k"], cache["v"], block_table, start, valid,
-        k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"),
-        scale=cfg.head_dim ** -0.5, impl=attn_impl,
+        q[0], pages["kv"], block_table, start, valid, layer,
+        kv_scale=pages.get("kv_scale"), scale=cfg.head_dim ** -0.5,
+        impl=attn_impl,
     ).astype(x.dtype)  # (C, H_local, Dh)
     o = psum_tp(jnp.einsum("chk,hkd->cd", out, p["wo"]))[None]
-    return o, cache
+    return o, pages
 
 
 def mixed_step_attention_paged(
     p: dict,
     x: jax.Array,             # (R, 1, D) one token per row (decode + chunk)
-    layer_pages: dict,        # {"k": (P,page,KVH,Dh), "v": ...} this layer's pool
+    pages: dict,              # {"kv": (L,P,page,KVH*2*Dh)} the stacked pool
+    layer: jax.Array,         # scalar int32: this layer's index in the pool
     block_tables: jax.Array,  # (R, MP) int32, one block-table row per row
     positions: jax.Array,     # (R,) int32 absolute position per row, -1 = dead
     cfg: ModelConfig,
@@ -264,9 +269,9 @@ def mixed_step_attention_paged(
     num_decode: int | None = None,
 ) -> tuple[jax.Array, dict]:
     """Fused mixed step: decode rows AND one prefill chunk's rows scatter
-    their K/V into the page pool in ONE functional update, then every row
-    attends its own block table up to its own position (``<= positions[r]``)
-    through ``ops.paged_mixed_attention``.
+    their K/V into this layer of the page pool in ONE functional update,
+    then every row attends its own block table up to its own position
+    (``<= positions[r]``) through ``ops.paged_mixed_attention``.
 
     A decode slot contributes one row at ``positions[r] = length``; chunk
     token i contributes a row at ``positions[r] = start + i`` sharing the
@@ -292,23 +297,23 @@ def mixed_step_attention_paged(
     live = positions >= 0
     pos = jnp.maximum(positions, 0)
     q, k, v = _project_qkv(p, x, cfg, pos[:, None], rope)
-    num_pages, page = layer_pages["k"].shape[:2]
+    num_pages, page = pages["kv"].shape[1:3]
     phys = jnp.take_along_axis(
         block_tables, (pos // page)[:, None], axis=1
     )[:, 0]
     # dead rows and null-page entries write out of bounds and are DROPPED
     phys = jnp.where(live & (phys != 0), phys, num_pages)
     off = pos % page
-    cache = _paged_scatter(layer_pages, k[:, 0], v[:, 0], phys, off)
+    pages = _paged_scatter(pages, layer, k[:, 0], v[:, 0], phys, off)
     out = ops.paged_mixed_attention(
-        q[:, 0], cache["k"], cache["v"], block_tables, positions,
-        k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"),
-        scale=cfg.head_dim ** -0.5, impl=attn_impl, num_decode=num_decode,
+        q[:, 0], pages["kv"], block_tables, positions, layer,
+        kv_scale=pages.get("kv_scale"), scale=cfg.head_dim ** -0.5,
+        impl=attn_impl, num_decode=num_decode,
     ).astype(x.dtype)  # (R, H_local, Dh)
     # same sharding contract as decode: per-shard head slice of q/kv and the
     # page pool, tables/positions replicated, row-parallel wo reduced here
     o = psum_tp(jnp.einsum("bhk,hkd->bd", out, p["wo"]))[:, None, :]
-    return o, cache
+    return o, pages
 
 
 def cross_attention(

@@ -10,9 +10,10 @@ One class, five lowered entry points:
     one sequence scattered into its page set (chunked prefill)
 
 The layer stack is a ``lax.scan`` over stacked per-layer params (compile time
-O(1) in depth) with configurable ``jax.checkpoint`` policy. Vocab is padded to
-a multiple of 256 for clean TP sharding (padded logits are masked to -inf in
-the loss — exact math, standard Megatron practice).
+O(1) in depth) with configurable ``jax.checkpoint`` policy; the paged steps
+carry the stacked page pool through that scan and write it in place. Vocab
+is padded to a multiple of 256 for clean TP sharding (padded logits are
+masked to -inf in the loss — exact math, standard Megatron practice).
 """
 
 from __future__ import annotations
@@ -614,26 +615,21 @@ class DecoderLM:
     # ------------------------------------------------------------------
     # paged decode (continuous batching)
     # ------------------------------------------------------------------
-    def decode_step_paged(self, params, pages, block_tables, lengths, tokens):
-        """One token per in-flight slot against the paged KV pool.
-
-        pages: {"k": (L,P,page,KVH,Dh), "v": ...} — the shared page pool.
-        block_tables (S, MP) int32, lengths (S,) int32 (tokens already
-        cached per slot; idle slots are 0), tokens (S, 1) int32.
-        Returns (new_pages, logits (S, Vp) f32). Shapes are static across
-        admissions/evictions, so the jitted step never recompiles.
-        """
+    def _paged_layers(self, params, pages, x, attend):
+        """The dense layer stack over the paged pool. The stacked pool
+        rides the layer scan's CARRY (not its xs/ys), so each layer's
+        ``attend(p_attn, h, pages, layer) -> (h, pages)`` writes its new
+        rows in place at ``(layer, page, offset)`` and its kernel reads the
+        stacked pool at that layer: no per-layer slice, re-stacked pool or
+        whole-pool copy exists, and a donated pool comes back aliased.
+        Returns (x, pages)."""
         cfg = self.cfg
-        assert cfg.family in ("dense", "moe", "vlm"), cfg.family
-        x = jnp.take(params["embed"], tokens, axis=0)  # (S,1,D)
 
-        def body(x, inp):
-            pl, cl = inp
+        def body(carry, inp):
+            x, pages = carry
+            pl, layer = inp
             h = rms_norm(x, pl["ln1"], cfg.norm_eps)
-            h, new_cl = attn.decode_self_attention_paged(
-                pl["attn"], h, cl, block_tables, lengths, cfg,
-                attn_impl=self.attn_impl,
-            )
+            h, pages = attend(pl["attn"], h, pages, layer)
             x = x + h
             h = rms_norm(x, pl["ln2"], cfg.norm_eps)
             if cfg.family == "moe":
@@ -641,11 +637,36 @@ class DecoderLM:
             else:
                 h = swiglu(h, pl["mlp"]["w_gate"], pl["mlp"]["w_up"],
                            pl["mlp"]["w_down"])
-            return x + h, new_cl
+            return (x + h, pages), ()
 
-        x, new_pages = jax.lax.scan(
-            body, x, (params["layers"], dict(pages))
+        layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+        (x, pages), _ = jax.lax.scan(
+            body, (x, dict(pages)), (params["layers"], layers)
         )
+        return x, pages
+
+    def decode_step_paged(self, params, pages, block_tables, lengths, tokens):
+        """One token per in-flight slot against the paged KV pool.
+
+        pages: {"kv": (L,P,page,KVH*2*Dh)} — the shared page pool, stacked
+        over layers and lane-dense (``serving/kv_cache.py``), plus
+        "kv_scale" when int8. block_tables (S, MP) int32, lengths (S,)
+        int32 (tokens already cached per slot; idle slots are 0), tokens
+        (S, 1) int32. Returns (new_pages, logits (S, Vp) f32). Shapes are
+        static across admissions/evictions, so the jitted step never
+        recompiles.
+        """
+        cfg = self.cfg
+        assert cfg.family in ("dense", "moe", "vlm"), cfg.family
+        x = jnp.take(params["embed"], tokens, axis=0)  # (S,1,D)
+
+        def attend(p, h, pages, layer):
+            return attn.decode_self_attention_paged(
+                p, h, pages, layer, block_tables, lengths, cfg,
+                attn_impl=self.attn_impl,
+            )
+
+        x, new_pages = self._paged_layers(params, pages, x, attend)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         # column-parallel unembed under TP serving: gather the vocab shards
         # so sampling sees the full distribution (identity when unsharded)
@@ -679,25 +700,13 @@ class DecoderLM:
         assert cfg.family in ("dense", "moe"), cfg.family
         x = jnp.take(params["embed"], tokens, axis=0)  # (R,1,D)
 
-        def body(x, inp):
-            pl, cl = inp
-            h = rms_norm(x, pl["ln1"], cfg.norm_eps)
-            h, new_cl = attn.mixed_step_attention_paged(
-                pl["attn"], h, cl, block_tables, positions, cfg,
+        def attend(p, h, pages, layer):
+            return attn.mixed_step_attention_paged(
+                p, h, pages, layer, block_tables, positions, cfg,
                 attn_impl=self.attn_impl, num_decode=num_decode,
             )
-            x = x + h
-            h = rms_norm(x, pl["ln2"], cfg.norm_eps)
-            if cfg.family == "moe":
-                h, _ = moe_mod.moe_block(pl["moe"], h, cfg)
-            else:
-                h = swiglu(h, pl["mlp"]["w_gate"], pl["mlp"]["w_up"],
-                           pl["mlp"]["w_down"])
-            return x + h, new_cl
 
-        x, new_pages = jax.lax.scan(
-            body, x, (params["layers"], dict(pages))
-        )
+        x, new_pages = self._paged_layers(params, pages, x, attend)
         # decode rows + the chunk's sampling row, then ONE unembed
         xc = jax.lax.dynamic_slice_in_dim(
             x, num_decode + jnp.maximum(chunk_valid - 1, 0), 1, axis=0
@@ -717,7 +726,7 @@ class DecoderLM:
         """One fixed-size prefill chunk of ONE sequence, scattered into its
         existing page set.
 
-        pages: {"k": (L,P,page,KVH,Dh), "v": ...} — the shared page pool.
+        pages: the shared page pool, as in ``decode_step_paged``.
         block_table (MP,) int32 is the sequence's row; tokens (C,) int32 is
         the chunk (C static — one compile covers every prompt); start
         (scalar int32) is how many positions are already resident (shared
@@ -740,25 +749,13 @@ class DecoderLM:
         assert cfg.family in ("dense", "moe"), cfg.family
         x = jnp.take(params["embed"], tokens[None], axis=0)  # (1,C,D)
 
-        def body(x, inp):
-            pl, cl = inp
-            h = rms_norm(x, pl["ln1"], cfg.norm_eps)
-            h, new_cl = attn.prefill_chunk_attention_paged(
-                pl["attn"], h, cl, block_table, start, valid, cfg,
+        def attend(p, h, pages, layer):
+            return attn.prefill_chunk_attention_paged(
+                p, h, pages, layer, block_table, start, valid, cfg,
                 attn_impl=self.attn_impl,
             )
-            x = x + h
-            h = rms_norm(x, pl["ln2"], cfg.norm_eps)
-            if cfg.family == "moe":
-                h, _ = moe_mod.moe_block(pl["moe"], h, cfg)
-            else:
-                h = swiglu(h, pl["mlp"]["w_gate"], pl["mlp"]["w_up"],
-                           pl["mlp"]["w_down"])
-            return x + h, new_cl
 
-        x, new_pages = jax.lax.scan(
-            body, x, (params["layers"], dict(pages))
-        )
+        x, new_pages = self._paged_layers(params, pages, x, attend)
         x = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = all_gather_logits(jnp.einsum(
@@ -777,7 +774,7 @@ class DecoderLM:
         and attended exactly like a prefill chunk, but unembedding ALL C
         rows instead of just the last.
 
-        pages: {"k": (L,P,page,KVH,Dh), "v": ...} — the shared page pool.
+        pages: the shared page pool, as in ``decode_step_paged``.
         block_table (MP,) int32 is the sequence's row; tokens (C,) int32 is
         ``[t_last, d_1 .. d_k]`` padded to the static bundle width; start
         (scalar int32) is the sequence's cached length L (t_last's KV lands
@@ -799,25 +796,13 @@ class DecoderLM:
         assert cfg.family in ("dense", "moe"), cfg.family
         x = jnp.take(params["embed"], tokens[None], axis=0)  # (1,C,D)
 
-        def body(x, inp):
-            pl, cl = inp
-            h = rms_norm(x, pl["ln1"], cfg.norm_eps)
-            h, new_cl = attn.prefill_chunk_attention_paged(
-                pl["attn"], h, cl, block_table, start, valid, cfg,
+        def attend(p, h, pages, layer):
+            return attn.prefill_chunk_attention_paged(
+                p, h, pages, layer, block_table, start, valid, cfg,
                 attn_impl=self.attn_impl,
             )
-            x = x + h
-            h = rms_norm(x, pl["ln2"], cfg.norm_eps)
-            if cfg.family == "moe":
-                h, _ = moe_mod.moe_block(pl["moe"], h, cfg)
-            else:
-                h = swiglu(h, pl["mlp"]["w_gate"], pl["mlp"]["w_up"],
-                           pl["mlp"]["w_down"])
-            return x + h, new_cl
 
-        x, new_pages = jax.lax.scan(
-            body, x, (params["layers"], dict(pages))
-        )
+        x, new_pages = self._paged_layers(params, pages, x, attend)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)  # (1,C,D)
         logits = all_gather_logits(jnp.einsum(
             "bsd,dv->bsv", x, self._unembed_weight(params),
@@ -901,9 +886,10 @@ class DecoderLM:
         and writes the g-layer paged KV pool (g = L // attn_every) while
         every Mamba layer steps the per-slot state bank — one fused pass.
 
-        pages: {"k": (g,P,page,KVH,Dh), "v": ...}; state: the stacked
-        mamba bank (slot axis second); block_tables (S, MP) / lengths (S,)
-        index the attention pool exactly like ``decode_step_paged``.
+        pages: the g-layer pool, laid out as in ``decode_step_paged``;
+        state: the stacked mamba bank (slot axis second); block_tables (S,
+        MP) / lengths (S,) index the attention pool exactly like
+        ``decode_step_paged``.
         Returns (new_pages, new_state, logits (S, Vp) f32).
         """
         cfg = self.cfg
@@ -919,11 +905,12 @@ class DecoderLM:
         )
         shared = params["shared"]
 
-        def group_body(x, inp):
-            pg, mcg, cl = inp
+        def group_body(carry, inp):
+            x, pages = carry
+            pg, mcg, layer = inp
             h = rms_norm(x, shared["ln1"], cfg.norm_eps)
-            h, new_cl = attn.decode_self_attention_paged(
-                shared["attn"], h, cl, block_tables, lengths, cfg,
+            h, pages = attn.decode_self_attention_paged(
+                shared["attn"], h, pages, layer, block_tables, lengths, cfg,
                 attn_impl=self.attn_impl,
             )
             x = x + h
@@ -940,11 +927,16 @@ class DecoderLM:
                 return xc + hh, new_cl2
 
             x, new_mcs = jax.lax.scan(mbody, x, (pg, mcg))
-            return x, {"kv": new_cl, "mamba": new_mcs}
+            return (x, pages), new_mcs
 
-        x, ys = jax.lax.scan(group_body, x, (grouped, gstate, dict(pages)))
+        # the attention pool rides the carry and is written in place, as in
+        # ``_paged_layers``
+        (x, pages), ys = jax.lax.scan(
+            group_body, (x, dict(pages)),
+            (grouped, gstate, jnp.arange(g, dtype=jnp.int32)),
+        )
         new_state = jax.tree.map(
-            lambda a: a.reshape((cfg.num_layers,) + a.shape[2:]), ys["mamba"]
+            lambda a: a.reshape((cfg.num_layers,) + a.shape[2:]), ys
         )
         new_state = self._mask_state(new_state, dict(state), active)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -952,7 +944,7 @@ class DecoderLM:
             "bsd,dv->bsv", x, self._unembed_weight(params),
             preferred_element_type=jnp.float32,
         ))[:, 0]
-        return ys["kv"], new_state, logits
+        return pages, new_state, logits
 
     def prefill_chunk_hybrid(self, params, pages, state_slot, block_table,
                              tokens, start, valid):
@@ -976,12 +968,13 @@ class DecoderLM:
         )
         shared = params["shared"]
 
-        def group_body(x, inp):
-            pg, mcg, cl = inp
+        def group_body(carry, inp):
+            x, pages = carry
+            pg, mcg, layer = inp
             h = rms_norm(x, shared["ln1"], cfg.norm_eps)
-            h, new_cl = attn.prefill_chunk_attention_paged(
-                shared["attn"], h, cl, block_table, start, valid, cfg,
-                attn_impl=self.attn_impl,
+            h, pages = attn.prefill_chunk_attention_paged(
+                shared["attn"], h, pages, layer, block_table, start, valid,
+                cfg, attn_impl=self.attn_impl,
             )
             x = x + h
             h = rms_norm(x, shared["ln2"], cfg.norm_eps)
@@ -998,11 +991,14 @@ class DecoderLM:
                 return xc + hh, new_cl2
 
             x, new_mcs = jax.lax.scan(mbody, x, (pg, mcg))
-            return x, {"kv": new_cl, "mamba": new_mcs}
+            return (x, pages), new_mcs
 
-        x, ys = jax.lax.scan(group_body, x, (grouped, gstate, dict(pages)))
+        (x, pages), ys = jax.lax.scan(
+            group_body, (x, dict(pages)),
+            (grouped, gstate, jnp.arange(g, dtype=jnp.int32)),
+        )
         new_state = jax.tree.map(
-            lambda a: a.reshape((cfg.num_layers,) + a.shape[2:]), ys["mamba"]
+            lambda a: a.reshape((cfg.num_layers,) + a.shape[2:]), ys
         )
         x = jax.lax.dynamic_slice_in_dim(
             x, jnp.maximum(valid - 1, 0), 1, axis=1
@@ -1012,7 +1008,7 @@ class DecoderLM:
             "bsd,dv->bsv", x, self._unembed_weight(params),
             preferred_element_type=jnp.float32,
         ))[0, 0]
-        return ys["kv"], new_state, logits
+        return pages, new_state, logits
 
     @staticmethod
     def _mask_state(new_state, old_state, active):

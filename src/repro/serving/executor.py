@@ -11,10 +11,10 @@ Megatron-style tensor parallelism:
   ``psum_tp`` and the vocab-sharded logits gather with
   ``all_gather_logits`` (both marked inside the model code,
   identity when unsharded);
-* the KV page pool is sharded along its **head** dimension
-  (``(L, P, page, KVH, Dh)`` -> ``P(None, None, None, "model", None)``;
-  int8 pools add per-page scale arrays sharded the same way minus the
-  ``Dh`` axis), so every shard holds the SAME pages for its slice of heads — block
+* the KV page pool is sharded along its **lane** dimension, which holds
+  whole kv heads (``(L, P, page, KVH*2*Dh)`` -> ``P(None, None, None,
+  "model")``; an int8 pool's ``(L, P, page, KVH*2)`` scales shard the same
+  way), so every shard holds the SAME pages for its KVH/tp heads — block
   tables, page ids, refcounts and the prefix index stay single host-side
   structures in the :class:`~repro.serving.scheduler.Scheduler`;
 * everything the host feeds per step (block tables, lengths, tokens,
@@ -64,12 +64,10 @@ __all__ = [
     "validate_serving_mesh",
 ]
 
-# (L, P, page, KVH, Dh): only the head dim is sharded, so page ids and
+# every pool array is (L, P, page, lanes) with whole kv heads in contiguous
+# lanes (``kv_cache.py``): only the lanes are sharded, so page ids and
 # block-table entries mean the same thing on every shard
-PAGE_SPEC = P(None, None, None, "model", None)
-# int8 pools carry per-page-per-head scale arrays (L, P, page, KVH) — same
-# head sharding, no Dh axis
-SCALE_SPEC = P(None, None, None, "model")
+PAGE_SPEC = P(None, None, None, "model")
 
 _DEFAULT_MESH: Mesh | None = None
 
@@ -265,12 +263,8 @@ class ModelExecutor:
         return placed
 
     def _page_specs(self) -> dict:
-        """Per-array PartitionSpecs for the cache's page dict (scale arrays
-        drop the Dh axis but shard the same head dim)."""
-        return {
-            key: PAGE_SPEC if arr.ndim == 5 else SCALE_SPEC
-            for key, arr in self.cache.pages.items()
-        }
+        """Per-array PartitionSpecs for the cache's page dict."""
+        return {key: PAGE_SPEC for key in self.cache.pages}
 
     def _smap(self, fn, in_specs, out_specs):
         # replication checking off: scatters and gathered logits are
@@ -653,8 +647,8 @@ class ModelExecutor:
                         params, batch, s_total, logits_index=idx
                     )
                     # cache["k"] is (L, 1, S, KVH/tp, Dh): the local head
-                    # slice scatters into the local page shard — positions
-                    # and page ids are shard-invariant
+                    # slice scatters into the local lanes of the pool —
+                    # positions and page ids are shard-invariant
                     pages = write_prefill_pages(
                         pages, cache["k"][:, 0], cache["v"][:, 0],
                         row, valid_len,

@@ -1,22 +1,32 @@
 """Block-table KV cache: refcounted pages with prefix sharing + copy-on-write.
 
-The device side is a dict of page-pool arrays per model — ``pages["k"]`` /
-``pages["v"]`` of shape (L, P, page_size, KVH, Dh), plus per-(position, head)
-``pages["k_scale"]`` / ``pages["v_scale"]`` of shape (L, P, page_size, KVH)
-when the pool is int8-quantized — plus per-step int32 inputs (block tables
-and lengths), so the jitted decode step sees ONE static shape no matter how
-many sequences are in flight or how long each one is. The host side is a
-refcounted free-list allocator (:class:`PagePool`) and per-slot bookkeeping
-(:class:`PagedKVCache`) that hands the engine ready-to-transfer block tables.
+The device side is a dict of page-pool arrays per model: ``pages["kv"]`` of
+shape (L, P, page_size, KVH*2*Dh) holds K and V of every layer in ONE
+lane-dense array — kv head h's K then its V in lanes [2*Dh*h, 2*Dh*(h+1))
+(``ref.pack_kv``), so a page's two minor dims fill whole (16, 128) bf16
+tiles for Dh = 64 where a (..., KVH, Dh) page would pad them 3.2x at KVH 5
+— plus ``pages["kv_scale"]`` of shape (L, P, page_size, KVH*2) (head h's K
+scale then its V scale, ``ref.pack_kv_scales``) when the pool is
+int8-quantized; and per-step int32 inputs (block tables and lengths), so
+the jitted decode step sees ONE static shape no matter how many sequences
+are in flight or how long each one is. The pool stays in that one layout
+from allocation to kernel: the model's layer loop carries it, writes each
+layer's new rows in place at (layer, page, offset), and the paged kernels
+read the stacked pool at a scalar-prefetched layer, so a step moves no
+pool-sized data. ``ref.unpack_kv`` is the logical (..., KVH, Dh) view the
+oracles and tests read. The host side is a refcounted free-list allocator
+(:class:`PagePool`) and per-slot bookkeeping (:class:`PagedKVCache`) that
+hands the engine ready-to-transfer block tables.
 
 Sharded serving (the scheduler/executor split) places the pool on a
-``("model",)`` mesh sharded along the **kv-head** dim only
-(:meth:`PagedKVCache._reshard`): every shard then holds the same physical
-pages for its slice of heads, so page ids, block tables, refcounts and the
-prefix index below are shard-invariant and stay SINGLE host-side
+``("model",)`` mesh sharded along the **lane** dim only, which holds whole
+kv heads (:meth:`PagedKVCache._reshard`): every shard then holds the same
+physical pages for its KVH/tp heads, so page ids, block tables, refcounts
+and the prefix index below are shard-invariant and stay SINGLE host-side
 structures — nothing in this module knows how many devices exist. The
 copy-on-write page copy (:func:`_copy_page`) slices along the page dim,
-which keeps the head sharding intact.
+which keeps the head sharding intact; tier spill and revive move a page in
+the same packed layout.
 
 Page 0 is reserved as the **null page**: unused block-table entries and idle
 decode slots point at it, so the kernel's gathers never go out of bounds and
@@ -69,7 +79,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.ref import dequantize_pages, quantize_kv
+from repro.kernels.ref import pack_kv, pack_kv_scales, quantize_kv, unpack_kv
 from repro.serving.kv_tiers import KVTierManager, chain_key
 from repro.serving.metrics import phase
 
@@ -179,8 +189,8 @@ class PagedKVCache:
     :meth:`swap_pages`).
 
     ``quant="int8"`` stores K/V as int8 with one f32 scale per
-    (page, position, kv head) in ``pages["k_scale"]``/``pages["v_scale"]``
-    — ~4x more pages per HBM byte; the paged kernels fuse the dequant.
+    (page, position, kv head) for K and for V in ``pages["kv_scale"]`` —
+    ~4x more pages per HBM byte; the paged kernels fuse the dequant.
     ``tiers`` attaches a :class:`~repro.serving.kv_tiers.KVTierManager`
     (see module docstring).
     """
@@ -208,15 +218,16 @@ class PagedKVCache:
         self.num_pages = num_pages
         self.quant = quant
         self.tiers = tiers
-        shape = (num_layers, num_pages, page_size, num_kv_heads, head_dim)
+        self.head_dim = head_dim
+        rows = (num_layers, num_pages, page_size)
         store_dtype = jnp.int8 if quant == "int8" else dtype
         self.pages: dict[str, jax.Array] = {
-            "k": jnp.zeros(shape, store_dtype),
-            "v": jnp.zeros(shape, store_dtype),
+            "kv": jnp.zeros(rows + (num_kv_heads * 2 * head_dim,),
+                            store_dtype),
         }
         if quant == "int8":
-            self.pages["k_scale"] = jnp.zeros(shape[:-1], jnp.float32)
-            self.pages["v_scale"] = jnp.zeros(shape[:-1], jnp.float32)
+            self.pages["kv_scale"] = jnp.zeros(rows + (num_kv_heads * 2,),
+                                               jnp.float32)
 
         self.pool = PagePool(num_pages)
         self.block_tables = np.full(
@@ -602,22 +613,6 @@ class PagedKVCache:
     # device views
     # ------------------------------------------------------------------
     @property
-    def k_pages(self) -> jax.Array:
-        return self.pages["k"]
-
-    @k_pages.setter
-    def k_pages(self, value: jax.Array) -> None:
-        self.pages["k"] = value
-
-    @property
-    def v_pages(self) -> jax.Array:
-        return self.pages["v"]
-
-    @v_pages.setter
-    def v_pages(self, value: jax.Array) -> None:
-        self.pages["v"] = value
-
-    @property
     def page_nbytes(self) -> int:
         """Device bytes per physical page across every pool array (K, V and
         quantization scales) — the denominator for pages-per-HBM-byte."""
@@ -645,9 +640,6 @@ class PagedKVCache:
         """Device copy of one slot's block-table row (same aliasing rule)."""
         return jnp.asarray(self.block_tables[slot].copy())
 
-    def set_pages(self, k_pages: jax.Array, v_pages: jax.Array) -> None:
-        self.pages["k"], self.pages["v"] = k_pages, v_pages
-
     def swap_pages(self, pages: dict[str, jax.Array]) -> None:
         """Swap in the executor's post-step page arrays (donated calls)."""
         assert set(pages) == set(self.pages), (set(pages), set(self.pages))
@@ -655,24 +647,26 @@ class PagedKVCache:
 
     def _reshard(self, sharding) -> None:
         """Re-place the page pool with explicit shardings (the serving
-        executor shards the kv-head dim over its ``("model",)`` mesh) —
-        either one sharding for every pool array or a dict keyed like
-        ``pages``. Host-side bookkeeping is untouched: only the head dim
-        may be sharded, so page ids stay shard-invariant."""
+        executor shards the lane dim, whole kv heads, over its
+        ``("model",)`` mesh) — either one sharding for every pool array or
+        a dict keyed like ``pages``. Host-side bookkeeping is untouched:
+        only the lanes may be sharded, so page ids stay shard-invariant."""
         if not isinstance(sharding, dict):
             sharding = {key: sharding for key in self.pages}
         for key in self.pages:
             self.pages[key] = jax.device_put(self.pages[key], sharding[key])
 
+    def unpacked(self) -> tuple[jax.Array, jax.Array]:
+        """The whole pool's logical (K, V), each (L, P, page, KVH, Dh),
+        through ``ref.unpack_kv`` — quantized pools come back dequantized
+        (f32). A copy for tests and inspection, never the serving path."""
+        return unpack_kv(self.pages["kv"], self.head_dim,
+                         self.pages.get("kv_scale"))
+
     def gather_dense(self, slot: int) -> tuple[np.ndarray, np.ndarray]:
         """Reassemble a slot's K/V as dense (L, len, KVH, Dh) — tests only.
         Quantized pools are dequantized, so callers compare fp32 values."""
-        if self.quant == "int8":
-            k = np.asarray(dequantize_pages(self.pages["k"], self.pages["k_scale"]))
-            v = np.asarray(dequantize_pages(self.pages["v"], self.pages["v_scale"]))
-        else:
-            k = np.asarray(self.pages["k"])
-            v = np.asarray(self.pages["v"])
+        k, v = (np.asarray(a) for a in self.unpacked())
         n = int(self.lengths[slot])
         pages = self._slot_pages[slot]
         out_k = np.concatenate([k[:, p] for p in pages], axis=1)[:, :n]
@@ -687,27 +681,27 @@ def write_prefill_pages(
     table_row: jax.Array,  # (MP,) int32 physical page per logical page
     valid_len: jax.Array,  # scalar int32: positions < valid_len are real
 ) -> dict[str, jax.Array]:
-    """Scatter one sequence's dense prefill K/V into its pages.
+    """Scatter one sequence's dense prefill K/V, packed, into its pages of
+    every layer.
 
     Padded positions (>= valid_len) are routed out of bounds and dropped —
     bucketed prompt padding never lands anywhere, and every surviving
     scatter index is unique (duplicate-index scatter order is undefined).
-    Quantized pools (``k_scale`` present) quantize the dense chunk on the
+    Quantized pools (``kv_scale`` present) quantize the dense chunk on the
     way in and scatter the scales alongside.
     """
-    num_pages, page = pages["k"].shape[1:3]
+    num_pages, page = pages["kv"].shape[1:3]
     s = k_new.shape[1]
     pos = jnp.arange(s)
     phys = jnp.where(pos < valid_len, table_row[pos // page], num_pages)
     off = pos % page
     out = dict(pages)
-    if "k_scale" in pages:
+    if "kv_scale" in pages:
         k_new, k_sc = quantize_kv(k_new)
         v_new, v_sc = quantize_kv(v_new)
-        out["k_scale"] = pages["k_scale"].at[:, phys, off].set(k_sc, mode="drop")
-        out["v_scale"] = pages["v_scale"].at[:, phys, off].set(v_sc, mode="drop")
-    out["k"] = pages["k"].at[:, phys, off].set(
-        k_new.astype(pages["k"].dtype), mode="drop")
-    out["v"] = pages["v"].at[:, phys, off].set(
-        v_new.astype(pages["v"].dtype), mode="drop")
+        out["kv_scale"] = pages["kv_scale"].at[:, phys, off].set(
+            pack_kv_scales(k_sc, v_sc), mode="drop")
+    dtype = pages["kv"].dtype
+    out["kv"] = pages["kv"].at[:, phys, off].set(
+        pack_kv(k_new.astype(dtype), v_new.astype(dtype)), mode="drop")
     return out

@@ -53,7 +53,6 @@ from repro.serving.api import (
 )
 from repro.serving.executor import (
     PAGE_SPEC,
-    SCALE_SPEC,
     _serving_param_specs,
     default_serving_mesh,
     validate_serving_mesh,
@@ -178,10 +177,7 @@ class SSMExecutor:
         return placed
 
     def _page_specs(self) -> dict:
-        return {
-            key: PAGE_SPEC if arr.ndim == 5 else SCALE_SPEC
-            for key, arr in self.cache.pages.items()
-        }
+        return {key: PAGE_SPEC for key in self.cache.pages}
 
     def _smap(self, fn, in_specs, out_specs):
         # replication checking off: scatters and gathered logits are

@@ -26,6 +26,26 @@ from repro.serving.kv_cache import NULL_PAGE, cdiv
 TOL = 1e-3  # max abs error bound, kernel vs oracle (f32; observed ~1e-6)
 
 
+LAYER = 1  # every pool below stacks 3 layers; the calls read the middle one
+
+
+def _stack(kp, vp):
+    """Logical (P, page, KVH, D) K and V pages -> the serving pool's
+    stacked lane-dense layout (``ref.pack_kv``), the case's pages at layer
+    ``LAYER`` between two layers of other values, so a kernel that reads
+    the wrong layer or mixes up K and V lanes fails the comparison."""
+    kv = ref.pack_kv(jnp.asarray(kp), jnp.asarray(vp))
+    other = jnp.full_like(kv, 3)
+    return jnp.stack([other, kv, -other])
+
+
+def _stack_q(kq, ks, vq, vs):
+    """The int8 counterpart of :func:`_stack`: (pool, scales)."""
+    sc = ref.pack_kv_scales(ks, vs)
+    return _stack(kq, vq), jnp.stack(
+        [jnp.full_like(sc, 0.5), sc, jnp.full_like(sc, 2.0)])
+
+
 def _assert_close(got, want, params, kind):
     err = float(jnp.abs(jnp.asarray(got, jnp.float32)
                         - jnp.asarray(want, jnp.float32)).max())
@@ -102,7 +122,8 @@ def test_paged_prefill_kernel_vs_oracle(params):
         q, kp, vp, bt, start, valid = _prefill_case(params, seed, forked)
         want = ref.paged_prefill_attention_ref(q, kp, vp, bt, start, valid)
         got = ops.paged_prefill_attention(
-            q, kp, vp, bt, start, valid, impl="pallas_interpret"
+            q, _stack(kp, vp), bt, start, valid, LAYER,
+            impl="pallas_interpret",
         )
         _assert_close(got, want, params + (("forked",) if forked else ()),
                       "paged_prefill")
@@ -158,8 +179,8 @@ def test_paged_prefill_chunk_walk_matches_dense():
             qc = np.zeros((chunk, h, d), np.float32)
             qc[:valid] = qd[start:start + valid]
             out = ops.paged_prefill_attention(
-                jnp.asarray(qc), jnp.asarray(kp), jnp.asarray(vp),
-                jnp.asarray(bt), jnp.int32(start), jnp.int32(valid),
+                jnp.asarray(qc), _stack(kp, vp),
+                jnp.asarray(bt), jnp.int32(start), jnp.int32(valid), LAYER,
                 impl="pallas_interpret",
             )
             outs.append(np.asarray(out)[:valid])
@@ -186,7 +207,7 @@ def test_paged_prefill_property(c, start, pad, group, kvh, page, seed):
     params = (c, start, valid, kvh * group, kvh, 8, page, 1)
     q, kp, vp, bt, s_, v_ = _prefill_case(params, seed)
     want = ref.paged_prefill_attention_ref(q, kp, vp, bt, s_, v_)
-    got = ops.paged_prefill_attention(q, kp, vp, bt, s_, v_,
+    got = ops.paged_prefill_attention(q, _stack(kp, vp), bt, s_, v_, LAYER,
                                       impl="pallas_interpret")
     _assert_close(got, want, params + (seed,), "paged_prefill_property")
     # convexity: live rows are convex combinations of V rows
@@ -252,8 +273,10 @@ def _decode_case(params, seed):
 def test_paged_decode_kernel_vs_oracle(params):
     for seed in (0, 1):
         q, kp, vp, bt, lens = _decode_case(params, seed)
-        want = ops.paged_attention(q, kp, vp, bt, lens, impl="xla_chunked")
-        got = ops.paged_attention(q, kp, vp, bt, lens,
+        pool = _stack(kp, vp)
+        want = ops.paged_attention(q, pool, bt, lens, LAYER,
+                                   impl="xla_chunked")
+        got = ops.paged_attention(q, pool, bt, lens, LAYER,
                                   impl="pallas_interpret")
         _assert_close(got, want, params + (seed,), "paged_decode")
         if int(lens[0]) == 0:
@@ -265,13 +288,15 @@ def test_paged_decode_equals_prefill_c1():
     """Cross-kernel consistency: decode is the C=1 chunk case."""
     params = (3, 4, 2, 16, 8, 3, False)
     q, kp, vp, bt, lens = _decode_case(params, seed=5)
-    dec = ops.paged_attention(q, kp, vp, bt, lens, impl="pallas_interpret")
+    pool = _stack(kp, vp)
+    dec = ops.paged_attention(q, pool, bt, lens, LAYER,
+                              impl="pallas_interpret")
     for i in range(q.shape[0]):
         n = int(lens[i])
         if n == 0:
             continue
         chunk = ops.paged_prefill_attention(
-            q[i][None], kp, vp, bt[i], jnp.int32(n - 1), jnp.int32(1),
+            q[i][None], pool, bt[i], jnp.int32(n - 1), jnp.int32(1), LAYER,
             impl="pallas_interpret",
         )[0]
         _assert_close(chunk, dec[i], params + (i,), "decode_vs_prefill_c1")
@@ -341,9 +366,10 @@ def _mixed_case(params, seed):
 def test_paged_mixed_kernel_vs_oracle(params):
     for seed in (0, 1):
         q, kp, vp, bt, last = _mixed_case(params, seed)
-        want = ops.paged_mixed_attention(q, kp, vp, bt, last,
+        pool = _stack(kp, vp)
+        want = ops.paged_mixed_attention(q, pool, bt, last, LAYER,
                                          impl="xla_chunked")
-        got = ops.paged_mixed_attention(q, kp, vp, bt, last,
+        got = ops.paged_mixed_attention(q, pool, bt, last, LAYER,
                                         impl="pallas_interpret")
         _assert_close(got, want, params + (seed,), "paged_mixed")
         dead = np.asarray(last) < 0
@@ -359,19 +385,22 @@ def test_paged_mixed_subsumes_decode_and_chunk():
     params = (4, 4, 2, 16, 8, 3, 1, 0)
     q, kp, vp, bt, last = _mixed_case(params, seed=3)
     lens = jnp.asarray(np.maximum(np.asarray(last) + 1, 0))
-    dec = ops.paged_attention(q, kp, vp, bt, lens, impl="pallas_interpret")
-    mix = ops.paged_mixed_attention(q, kp, vp, bt, last,
+    pool = _stack(kp, vp)
+    dec = ops.paged_attention(q, pool, bt, lens, LAYER,
+                              impl="pallas_interpret")
+    mix = ops.paged_mixed_attention(q, pool, bt, last, LAYER,
                                     impl="pallas_interpret")
     _assert_close(mix, dec, params, "mixed_vs_decode")
 
     c, start, h, kvh, d, page = 8, 5, 4, 2, 16, 8
     cp = (c, start, c, h, kvh, d, page, 1)
     qc, kpc, vpc, btc, s_, v_ = _prefill_case(cp, seed=7)
-    chunk = ops.paged_prefill_attention(qc, kpc, vpc, btc, s_, v_,
+    poolc = _stack(kpc, vpc)
+    chunk = ops.paged_prefill_attention(qc, poolc, btc, s_, v_, LAYER,
                                         impl="pallas_interpret")
     mixc = ops.paged_mixed_attention(
-        qc, kpc, vpc, jnp.broadcast_to(btc, (c,) + btc.shape),
-        jnp.int32(start) + jnp.arange(c, dtype=jnp.int32),
+        qc, poolc, jnp.broadcast_to(btc, (c,) + btc.shape),
+        jnp.int32(start) + jnp.arange(c, dtype=jnp.int32), LAYER,
         impl="pallas_interpret",
     )
     _assert_close(mixc, chunk, cp, "mixed_vs_chunk")
@@ -392,9 +421,10 @@ def test_paged_mixed_structured_xla_matches_oracle():
         if dead_tail:
             last[r - dead_tail:] = -1  # chunk padding: a dead suffix
         last = jnp.asarray(last)
-        want = ops.paged_mixed_attention(q, kp, vp, bt, last,
+        pool = _stack(kp, vp)
+        want = ops.paged_mixed_attention(q, pool, bt, last, LAYER,
                                          impl="xla_chunked")
-        got = ops.paged_mixed_attention(q, kp, vp, bt, last,
+        got = ops.paged_mixed_attention(q, pool, bt, last, LAYER,
                                         impl="xla_chunked",
                                         num_decode=r - ck)
         _assert_close(got, want, params + (dead_tail,), "mixed_structured")
@@ -436,20 +466,21 @@ def test_paged_verify_equals_decode_loop(params):
     for seed, forked in ((0, False), (1, True)):
         q, kp, vp, bt, start, valid = _prefill_case(params, seed, forked)
         want = ref.paged_verify_attention_ref(q, kp, vp, bt, start, valid)
-        chunk = ops.paged_prefill_attention(q, kp, vp, bt, start, valid,
+        pool = _stack(kp, vp)
+        chunk = ops.paged_prefill_attention(q, pool, bt, start, valid, LAYER,
                                             impl="pallas_interpret")
         _assert_close(chunk, want, params + (seed,), "verify_vs_chunk")
         mixed = ops.paged_mixed_attention(
-            q, kp, vp, jnp.broadcast_to(bt, (q.shape[0],) + bt.shape),
+            q, pool, jnp.broadcast_to(bt, (q.shape[0],) + bt.shape),
             jnp.where(jnp.arange(q.shape[0]) < valid,
                       start + jnp.arange(q.shape[0]), -1).astype(jnp.int32),
-            impl="pallas_interpret",
+            LAYER, impl="pallas_interpret",
         )
         _assert_close(mixed, want, params + (seed,), "verify_vs_mixed")
         for j in range(int(valid)):  # the decode loop the bundle replaces
             dec = ops.paged_attention(
-                q[j][None], kp, vp, bt[None],
-                jnp.asarray([int(start) + j + 1], jnp.int32),
+                q[j][None], pool, bt[None],
+                jnp.asarray([int(start) + j + 1], jnp.int32), LAYER,
                 impl="xla_chunked",
             )[0]
             _assert_close(dec, want[j], params + (seed, j),
@@ -472,8 +503,8 @@ def test_paged_verify_invariant_to_page_relocation():
     kp2[spare], vp2[spare] = kp2[live[0]], vp2[live[0]]
     bt2[np.asarray(bt).tolist().index(live[0])] = spare
     moved = ops.paged_prefill_attention(
-        q, jnp.asarray(kp2), jnp.asarray(vp2), jnp.asarray(bt2),
-        start, valid, impl="pallas_interpret",
+        q, _stack(kp2, vp2), jnp.asarray(bt2),
+        start, valid, LAYER, impl="pallas_interpret",
     )
     _assert_close(moved, base, params, "verify_page_relocation")
 
@@ -635,8 +666,9 @@ def test_paged_prefill_quantized_kernel_vs_oracle(params):
         q, ref.dequantize_pages(kq, ks), ref.dequantize_pages(vq, vs),
         bt, start, valid,
     )
+    pool, sc = _stack_q(kq, ks, vq, vs)
     got = ops.paged_prefill_attention(
-        q, kq, vq, bt, start, valid, k_scale=ks, v_scale=vs,
+        q, pool, bt, start, valid, LAYER, kv_scale=sc,
         impl="pallas_interpret",
     )
     _assert_close(got, want, params, "paged_prefill_q")
@@ -653,13 +685,15 @@ def test_paged_decode_quantized_kernel_vs_oracle(params):
     q, kp, vp, bt, lens = _decode_case(params, seed=0)
     kq, ks, vq, vs = _quantize_pool(kp, vp)
     want = ops.paged_attention(
-        q, ref.dequantize_pages(kq, ks), ref.dequantize_pages(vq, vs),
-        bt, lens, impl="xla_chunked",
+        q, _stack(ref.dequantize_pages(kq, ks), ref.dequantize_pages(vq, vs)),
+        bt, lens, LAYER, impl="xla_chunked",
     )
-    got = ops.paged_attention(q, kq, vq, bt, lens, k_scale=ks, v_scale=vs,
+    pool, sc = _stack_q(kq, ks, vq, vs)
+    got = ops.paged_attention(q, pool, bt, lens, LAYER, kv_scale=sc,
                               impl="pallas_interpret")
     _assert_close(got, want, params, "paged_decode_q")
-    fp32 = ops.paged_attention(q, kp, vp, bt, lens, impl="xla_chunked")
+    fp32 = ops.paged_attention(q, _stack(kp, vp), bt, lens, LAYER,
+                               impl="xla_chunked")
     err = float(jnp.abs(got - fp32).max())
     assert err <= QTOL, f"paged_decode int8-vs-fp32 err={err:.3e} > {QTOL}"
     if int(lens[0]) == 0:
@@ -672,14 +706,15 @@ def test_paged_mixed_quantized_kernel_vs_oracle(params):
     q, kp, vp, bt, last = _mixed_case(params, seed=0)
     kq, ks, vq, vs = _quantize_pool(kp, vp)
     want = ops.paged_mixed_attention(
-        q, ref.dequantize_pages(kq, ks), ref.dequantize_pages(vq, vs),
-        bt, last, impl="xla_chunked",
+        q, _stack(ref.dequantize_pages(kq, ks), ref.dequantize_pages(vq, vs)),
+        bt, last, LAYER, impl="xla_chunked",
     )
-    got = ops.paged_mixed_attention(q, kq, vq, bt, last,
-                                    k_scale=ks, v_scale=vs,
+    pool, sc = _stack_q(kq, ks, vq, vs)
+    got = ops.paged_mixed_attention(q, pool, bt, last, LAYER, kv_scale=sc,
                                     impl="pallas_interpret")
     _assert_close(got, want, params, "paged_mixed_q")
-    fp32 = ops.paged_mixed_attention(q, kp, vp, bt, last, impl="xla_chunked")
+    fp32 = ops.paged_mixed_attention(q, _stack(kp, vp), bt, last, LAYER,
+                                     impl="xla_chunked")
     err = float(jnp.abs(got - fp32).max())
     assert err <= QTOL, f"paged_mixed int8-vs-fp32 err={err:.3e} > {QTOL}"
     dead = np.asarray(last) < 0
@@ -751,11 +786,11 @@ def _pallas_off_tpu_calls():
         "flash_attention": lambda: ops.flash_attention(
             fq, fq, fq, impl="pallas"),
         "paged_attention": lambda: ops.paged_attention(
-            qd, kpd, vpd, btd, lens, impl="pallas"),
+            qd, _stack(kpd, vpd), btd, lens, LAYER, impl="pallas"),
         "paged_prefill_attention": lambda: ops.paged_prefill_attention(
-            q, kp, vp, bt, start, valid, impl="pallas"),
+            q, _stack(kp, vp), bt, start, valid, LAYER, impl="pallas"),
         "paged_mixed_attention": lambda: ops.paged_mixed_attention(
-            qm, kpm, vpm, btm, last, impl="pallas"),
+            qm, _stack(kpm, vpm), btm, last, LAYER, impl="pallas"),
         "ssd_scan": lambda: ops.ssd_scan(x, dt, a, bm, bm, impl="pallas"),
         "ssd_decode_step": lambda: ops.ssd_decode_step(
             *_ssd_decode_case((2, 4, 16, 32), 0), impl="pallas"),

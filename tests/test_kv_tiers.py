@@ -286,9 +286,11 @@ def test_int8_pages_double_admission_at_equal_pool_bytes():
 def test_quantized_pool_array_shapes_and_page_bytes():
     fp = _cache()
     q = _cache(quant="int8")
-    assert set(q.pages) == {"k", "v", "k_scale", "v_scale"}
-    assert q.pages["k"].dtype == jnp.int8
-    assert q.pages["k_scale"].shape == q.pages["k"].shape[:-1]
+    assert set(q.pages) == {"kv", "kv_scale"}
+    assert q.pages["kv"].dtype == jnp.int8
+    # one f32 scale per (layer, page, position, kv head) for K and for V
+    assert q.pages["kv_scale"].shape == q.pages["kv"].shape[:-1] + (
+        q.pages["kv"].shape[-1] // q.head_dim,)
     # int8 + f32 scales must beat fp32 by >= 2x per page for head_dim >= 8
     assert fp.page_nbytes >= 2 * q.page_nbytes
 

@@ -124,9 +124,10 @@ def test_paged_attention_pallas_matches_ref(rng):
     for seed in range(3):
         r = np.random.default_rng(seed)
         q, kp, vp, bt, lens = _random_paged_case(r)
-        o_ref = ops.paged_attention(q, kp, vp, bt, lens, impl="xla_chunked")
+        pool = ref.pack_kv(kp, vp)[None]
+        o_ref = ops.paged_attention(q, pool, bt, lens, 0, impl="xla_chunked")
         o_pal = ops.paged_attention(
-            q, kp, vp, bt, lens, impl="pallas", interpret=True
+            q, pool, bt, lens, 0, impl="pallas", interpret=True
         )
         err = float(jnp.abs(o_ref - o_pal).max())
         assert err <= 1e-3, err
@@ -135,9 +136,10 @@ def test_paged_attention_pallas_matches_ref(rng):
 def test_paged_attention_gqa_and_mqa(rng):
     for kvh in (1, 4):
         q, kp, vp, bt, lens = _random_paged_case(rng, h=4, kvh=kvh)
-        o_ref = ops.paged_attention(q, kp, vp, bt, lens, impl="xla_chunked")
+        pool = ref.pack_kv(kp, vp)[None]
+        o_ref = ops.paged_attention(q, pool, bt, lens, 0, impl="xla_chunked")
         o_pal = ops.paged_attention(
-            q, kp, vp, bt, lens, impl="pallas", interpret=True
+            q, pool, bt, lens, 0, impl="pallas", interpret=True
         )
         assert float(jnp.abs(o_ref - o_pal).max()) <= 1e-3
 
@@ -153,7 +155,8 @@ def test_paged_prefill_pallas_matches_ref(rng):
     kp = jnp.asarray(rng.standard_normal((num_pages, page, kvh, d)), jnp.float32)
     vp = jnp.asarray(rng.standard_normal((num_pages, page, kvh, d)), jnp.float32)
     bt = jnp.asarray([2, 1, 3], jnp.int32)
-    args = (q, kp, vp, bt, jnp.int32(start), jnp.int32(valid))
+    args = (q, ref.pack_kv(kp, vp)[None], bt, jnp.int32(start),
+            jnp.int32(valid), 0)
     o_ref = ops.paged_prefill_attention(*args, impl="xla_chunked")
     o_pal = ops.paged_prefill_attention(*args, impl="pallas_interpret")
     assert float(jnp.abs(o_ref - o_pal).max()) <= 1e-3
@@ -236,7 +239,7 @@ def test_decode_step_paged_matches_dense(smollm):
         np.asarray(plogits[0]), dense_logits[0], atol=1e-4, rtol=1e-4
     )
 
-    pages = {"k": paged.k_pages, "v": paged.v_pages}
+    pages = dict(paged.pages)
     for i in range(steps):
         paged.ensure_append_capacity(slot)
         tokens = np.zeros((3, 1), np.int32)
@@ -250,6 +253,144 @@ def test_decode_step_paged_matches_dense(smollm):
             np.asarray(logits[slot]), dense_logits[i + 1], atol=1e-4, rtol=1e-4
         )
         assert np.isfinite(np.asarray(logits)).all()  # idle slots too
+
+
+# ---------------------------------------------------------------------------
+# page pool layout: what the writers pack, the unpack view reads back
+# ---------------------------------------------------------------------------
+
+LAYOUT = dict(num_layers=3, num_kv_heads=2, head_dim=8, dtype=jnp.bfloat16,
+              max_slots=2, max_context=64, page_size=8, num_pages=8)
+
+
+def _stored(rows, quant):
+    """What a pool reads back for written f32 rows (..., KVH, D): the bf16
+    value, or for an int8 pool the row quantized and dequantized."""
+    rows = jnp.asarray(rows)
+    if quant == "int8":
+        return np.asarray(ref.dequantize_pages(*ref.quantize_kv(rows)))
+    return np.asarray(rows.astype(jnp.bfloat16).astype(jnp.float32))
+
+
+def _write(cache, slot, writer, n, seed, start=0):
+    """Write random K/V for positions [start, n) of ``slot`` into every
+    layer through one of the pool's writers: the model's per-layer row
+    write (``attention._paged_scatter``, with a dropped out-of-bounds row)
+    or the whole-prompt ``write_prefill_pages`` (from position 0, with
+    padded positions). Returns the logical (L, n - start, KVH, D) K and V
+    written."""
+    from repro.models.attention import _paged_scatter
+
+    rng = np.random.default_rng(seed)
+    shape = (LAYOUT["num_layers"], n - start, LAYOUT["num_kv_heads"],
+             LAYOUT["head_dim"])
+    k = rng.standard_normal(shape).astype(np.float32)
+    v = rng.standard_normal(shape).astype(np.float32)
+    row = cache.block_tables[slot]
+    page = cache.page_size
+    if writer == "prefill":  # three padded positions, to be dropped
+        assert start == 0
+        pad = np.full(shape[:1] + (3,) + shape[2:], 7, np.float32)
+        cache.swap_pages(write_prefill_pages(
+            dict(cache.pages), jnp.asarray(np.concatenate([k, pad], 1)),
+            jnp.asarray(np.concatenate([v, pad], 1)), jnp.asarray(row),
+            jnp.asarray(n, jnp.int32),
+        ))
+        return k, v
+    pos = np.arange(start, n + 1)
+    phys = np.where(pos < n, row[np.minimum(pos, n - 1) // page],
+                    cache.num_pages)  # the last row is out of bounds
+    pages = dict(cache.pages)
+    for layer in range(shape[0]):
+        pages = _paged_scatter(
+            pages, jnp.int32(layer),
+            jnp.asarray(np.concatenate([k[layer], k[layer, :1] + 7])),
+            jnp.asarray(np.concatenate([v[layer], v[layer, :1] + 7])),
+            jnp.asarray(phys, jnp.int32), jnp.asarray(pos % page, jnp.int32))
+    cache.swap_pages(pages)
+    return k, v
+
+
+def _read(cache, pages, n):
+    """Positions [0, n) of a page list through the unpack view:
+    (L, n, KVH, D) K and V."""
+    k, v = (np.asarray(a) for a in cache.unpacked())
+    page = cache.page_size
+    idx = [(pages[i // page], i % page) for i in range(n)]
+    return (np.stack([k[:, p, o] for p, o in idx], axis=1),
+            np.stack([v[:, p, o] for p, o in idx], axis=1))
+
+
+@pytest.mark.parametrize("writer", ["row", "prefill"])
+@pytest.mark.parametrize("quant", ["none", "int8"])
+def test_pool_layout_writers_roundtrip(quant, writer):
+    """A row written through each writer reads back equal through the
+    unpack view at every (layer, page, offset, head), K and V apart; the
+    dropped rows land nowhere (every other page stays zero)."""
+    cache = PagedKVCache(quant=quant, **LAYOUT)
+    n = 13  # one full page and a partial one
+    slot, _ = cache.admit(n)
+    k, v = _write(cache, slot, writer, n, seed=1)
+    got_k, got_v = _read(cache, cache._slot_pages[slot], n)
+    np.testing.assert_array_equal(got_k, _stored(k, quant))
+    np.testing.assert_array_equal(got_v, _stored(v, quant))
+    all_k, all_v = (np.asarray(a) for a in cache.unpacked())
+    others = [p for p in range(cache.num_pages)
+              if p not in cache._slot_pages[slot]]
+    assert not all_k[:, others].any() and not all_v[:, others].any()
+    assert not all_k[:, cache._slot_pages[slot][1], n % 8:].any()
+
+
+@pytest.mark.parametrize("quant", ["none", "int8"])
+def test_pool_layout_survives_cow_copy(quant):
+    """COW's page copy moves the packed page whole: the copy reads back
+    equal to its source at every (layer, offset, head), and a row written
+    into the copy leaves the source untouched."""
+    cache = PagedKVCache(quant=quant, **LAYOUT)
+    n = 13
+    a, _ = cache.admit(n)
+    k, v = _write(cache, a, "row", n, seed=2)
+    b = cache.fork(a)
+    assert cache.ensure_append_capacity(b)  # position 13: the tail page
+    pa, pb = cache._slot_pages[a], cache._slot_pages[b]
+    assert pa[1] != pb[1] and cache.stats["cow_copies"] == 1
+    got_k, got_v = _read(cache, pb, n)
+    np.testing.assert_array_equal(got_k, _stored(k, quant))
+    np.testing.assert_array_equal(got_v, _stored(v, quant))
+    k2, v2 = _write(cache, b, "row", n + 1, seed=3, start=8)  # b's copy
+    got_k, got_v = _read(cache, pb, n + 1)
+    np.testing.assert_array_equal(got_k[:, 8:], _stored(k2, quant))
+    np.testing.assert_array_equal(got_v[:, 8:], _stored(v2, quant))
+    src_k, src_v = _read(cache, pa, n)
+    np.testing.assert_array_equal(src_k, _stored(k, quant))
+    np.testing.assert_array_equal(src_v, _stored(v, quant))
+
+
+@pytest.mark.parametrize("quant", ["none", "int8"])
+def test_pool_layout_survives_spill_revive(quant):
+    """A parked prefix spilled to host RAM and prefetched back into other
+    device pages reads back equal through the unpack view."""
+    from repro.serving import KVTierManager
+
+    tiers = KVTierManager(host_pages=8)
+    cache = PagedKVCache(quant=quant, tiers=tiers, **LAYOUT)
+    toks = list(range(50, 75))  # 3 full pages + a tail
+    slot, _ = cache.admit(len(toks), toks)
+    k, v = _write(cache, slot, "row", len(toks), seed=4)
+    cache.register_prefix(slot, toks, len(toks))
+    chain = list(cache._slot_pages[slot][:3])
+    cache.release(slot)
+    big, _ = cache.admit(41, list(range(300, 341)))  # spills the chain
+    assert tiers.counters["spilled_pages"] == 3
+    cache.release(big)
+    assert not cache.can_admit(len(toks), toks)  # prefetch dispatched
+    cache.tick_tiers()
+    slot2, cached = cache.admit(len(toks), toks)
+    assert cached == 24
+    assert cache._slot_pages[slot2][:3] != chain  # revived elsewhere
+    got_k, got_v = _read(cache, cache._slot_pages[slot2], 24)
+    np.testing.assert_array_equal(got_k, _stored(k, quant)[:, :24])
+    np.testing.assert_array_equal(got_v, _stored(v, quant)[:, :24])
 
 
 # ---------------------------------------------------------------------------
@@ -429,10 +570,10 @@ def test_fork_cow_copies_exactly_one_page():
     toks = list(range(1, 13))  # 12 tokens: 1 full page + 1 partial
     a, _ = cache.admit(len(toks), toks)
     # fill the pool pages with recognizable data
-    k = cache.k_pages
+    kv = cache.pages["kv"]
     for i, p in enumerate(cache._slot_pages[a]):
-        k = k.at[:, p].set(float(i + 1))
-    cache.set_pages(k, cache.v_pages)
+        kv = kv.at[:, p].set(float(i + 1))
+    cache.swap_pages({"kv": kv})
 
     b = cache.fork(a)
     assert cache._slot_pages[b] == cache._slot_pages[a]
@@ -448,9 +589,8 @@ def test_fork_cow_copies_exactly_one_page():
     assert cache.pool.refcounts[pa[0]] == 2
     assert cache.pool.refcounts[pa[1]] == 1 and cache.pool.refcounts[pb[1]] == 1
     # the copy carried the tail page's contents
-    np.testing.assert_array_equal(
-        np.asarray(cache.k_pages[:, pb[1]]), np.asarray(cache.k_pages[:, pa[1]])
-    )
+    kv = np.asarray(cache.pages["kv"])
+    np.testing.assert_array_equal(kv[:, pb[1]], kv[:, pa[1]])
     # a's next append sees refcount 1 everywhere: no second copy
     assert not cache.ensure_append_capacity(a)
     assert cache.stats["cow_copies"] == 1
@@ -561,7 +701,7 @@ def test_prefill_chunk_matches_whole_prefill(smollm):
     )
     slot, _ = paged.admit(context_len=plen)
     row = paged.device_row(slot)
-    pages = {"k": paged.k_pages, "v": paged.v_pages}
+    pages = dict(paged.pages)
     logits = None
     for start in range(0, plen, chunk):
         valid = min(chunk, plen - start)
@@ -571,7 +711,7 @@ def test_prefill_chunk_matches_whole_prefill(smollm):
             params, pages, row, jnp.asarray(toks),
             jnp.asarray(start, jnp.int32), jnp.asarray(valid, jnp.int32),
         )
-    paged.set_pages(pages["k"], pages["v"])
+    paged.swap_pages(pages)
     got_k, got_v = paged.gather_dense(slot)
     np.testing.assert_allclose(
         got_k, np.asarray(dcache["k"][:, 0, :plen]), atol=1e-5, rtol=1e-5

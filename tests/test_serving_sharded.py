@@ -224,17 +224,18 @@ def test_executor_single_device_mesh_runs_everything(smollm):
                     reason="needs a multi-device host (CI forces 4 CPU "
                            "devices via XLA_FLAGS)")
 def test_pages_and_params_sharded_over_model_axis(smollm):
-    """The page pool shards along the kv-head dim (same pages on every
-    shard), attention weights shard along their head dims, and the token
-    embedding stays replicated."""
+    """The page pool shards along its lanes, whole kv heads per shard (same
+    pages on every shard), attention weights shard along their head dims,
+    and the token embedding stays replicated."""
     cfg, params = smollm
     eng = ContinuousBatchingEngine(cfg, params, max_len=64, max_slots=3,
                                    page_size=8)
     ex = eng.executor
     assert ex.tp >= 2
     kvh_local = cfg.eff_kv_heads // ex.tp
-    for shard in eng.cache.k_pages.addressable_shards:
-        assert shard.data.shape[3] == kvh_local       # head dim sharded
+    for shard in eng.cache.pages["kv"].addressable_shards:
+        # lane dim sharded: each shard holds its heads' K|V lane groups
+        assert shard.data.shape[3] == kvh_local * 2 * cfg.head_dim
         assert shard.data.shape[1] == eng.cache.num_pages  # pages NOT
     wq = ex.params["layers"]["attn"]["wq"]
     h_local = cfg.eff_heads // ex.tp
